@@ -59,7 +59,7 @@ _SKIP_PARAMS = frozenset(
         "compiler_options_kvs",
         "ctx_mesh",
         "mesh",
-        "check_rep",
+        "check_vma",
         "symbolic_zeros",
         "num_consts",  # rendered structurally via the sub-jaxpr split
         "jvp_jaxpr_fun",  # lu.WrappedFun, not a jaxpr
@@ -184,7 +184,7 @@ def dce(closed: jcore.ClosedJaxpr) -> jcore.ClosedJaxpr:
     load-balance scaling).  The contract covers computations that feed
     committed results, so both the prover and the hazard lint run on the
     DCE'd program.  Falls back to the original jaxpr if jax's internal
-    DCE entry point moves (the pinned jax==0.4.37 has it).
+    DCE entry point moves.
     """
     try:
         from jax._src.interpreters import partial_eval as pe
@@ -404,7 +404,7 @@ def eqn_source(eqn) -> tuple[str, int]:
     try:
         from jax._src import source_info_util
 
-        frames = list(source_info_util.user_frames(eqn.source_info))
+        frames = list(source_info_util.user_frames(eqn.source_info.traceback))
     except Exception:
         pass
     chosen = None
@@ -430,6 +430,7 @@ def eqn_source(eqn) -> tuple[str, int]:
         if idx >= 0:
             fname = fname[idx:]
             break
-    func = getattr(chosen, "function_name", "?")
+    # bare name, not the qualified ``outer.<locals>.inner`` form
+    func = str(getattr(chosen, "function_name", "?")).rsplit(".", 1)[-1]
     line = int(getattr(chosen, "start_line", 0) or 0)
     return f"{fname}::{func}", line

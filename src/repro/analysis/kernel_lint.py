@@ -19,11 +19,17 @@ shapes.  For every ``pl.pallas_call`` in scope this pass checks:
   literal-derived nor a whole input axis: partial shape-adaptive tiling.
 * ``accum-dtype``             — a VMEM scratch accumulator or a
   ``preferred_element_type`` narrower than f32 inside a kernel body: the
-  contract's combine dtype is f32.
+  contract's combine dtype is f32.  A scratch buffer the kernel only ever
+  fills by DMA (``make_async_copy`` destination, never stored to) is a
+  staging copy of its input, not an accumulator, and keeps the input dtype.
 * ``shape-branch-in-kernel``  — a Python ``if`` inside a kernel body: it
   branches at *trace time* on static arguments, so the compiled reduction
   structure depends on how the kernel was parameterized.  Runtime
   predication must use ``pl.when``.
+
+Grids and specs are read from the ``pallas_call`` keywords or from its
+``grid_spec=`` (``PrefetchScalarGridSpec``); a ``None`` block dim is a
+squeezed unit dim, i.e. the literal 1.
 
 Files or functions annotated ``# det: fastpath`` are exempt: they
 implement the *licensed* nondeterministic fast path (split-K, kv-split
@@ -60,7 +66,17 @@ class _Module:
         self.src = path.read_text()
         self.tree = ast.parse(self.src, filename=str(path))
         self.lines = self.src.splitlines()
-        self.file_fastpath = any(FASTPATH_RE.match(ln) for ln in self.lines)
+        # file-level exemption: the annotation sits in the module header,
+        # before the first def/class (below that it marks one function)
+        first_def = min(
+            (n.lineno for n in self.tree.body
+             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
+                               ast.ClassDef))),
+            default=len(self.lines) + 1,
+        )
+        self.file_fastpath = any(
+            FASTPATH_RE.match(ln) for ln in self.lines[: first_def - 1]
+        )
         self.module_assigns: Dict[str, ast.expr] = {}
         self.functions: Dict[str, ast.FunctionDef] = {}
         for node in self.tree.body:
@@ -134,7 +150,8 @@ class _FnCtx:
         if depth > 8:
             return False
         if isinstance(node, ast.Constant):
-            return isinstance(node.value, int)
+            # int literal, or None: a squeezed unit block dim
+            return node.value is None or isinstance(node.value, int)
         if isinstance(node, ast.Name):
             if node.id in self.shape_names or node.id in self.adaptive_names:
                 return False
@@ -182,6 +199,71 @@ def _resolve_kernel_fn(mod: _Module, entry: ast.expr) -> Optional[ast.FunctionDe
     if isinstance(entry, ast.Name):
         return mod.functions.get(entry.id)
     return None
+
+
+def _root_name(node: ast.expr) -> Optional[str]:
+    """``buf`` for ``buf``, ``buf[...]``, ``buf.at[...]`` and the like."""
+    while isinstance(node, (ast.Subscript, ast.Attribute)):
+        node = node.value
+    return node.id if isinstance(node, ast.Name) else None
+
+
+def _ref_root(node: ast.expr) -> Optional[str]:
+    """``buf`` for a ref expression ``buf`` or ``buf.at[...]``; ``None``
+    for anything else (``buf[...]`` as a value is a load, not the ref)."""
+    while (
+        isinstance(node, ast.Subscript)
+        and isinstance(node.value, ast.Attribute)
+        and node.value.attr == "at"
+    ):
+        node = node.value.value
+    return node.id if isinstance(node, ast.Name) else None
+
+
+def _dma_staging(mod: _Module, fn: ast.FunctionDef, name: str,
+                 depth: int = 0) -> tuple:
+    """``(clean, filled)`` for ref ``name`` inside ``fn``: ``clean`` means
+    no subscript store ever targets it (following it into module helpers
+    it is passed to), ``filled`` that it is some ``make_async_copy``
+    destination.  A scratch buffer that is clean and filled only ever
+    holds DMA'd input data."""
+    filled = False
+    for node in ast.walk(fn):
+        if (
+            isinstance(node, ast.Subscript)
+            and isinstance(node.ctx, ast.Store)
+            and _root_name(node) == name
+        ):
+            return False, filled
+        if not isinstance(node, ast.Call):
+            continue
+        if _tail(node.func) == "make_async_copy":
+            if len(node.args) >= 2 and _ref_root(node.args[1]) == name:
+                filled = True
+            continue
+        passed = [
+            (i, None) for i, a in enumerate(node.args) if _ref_root(a) == name
+        ] + [
+            (None, kw.arg) for kw in node.keywords
+            if _ref_root(kw.value) == name
+        ]
+        if not passed:
+            continue
+        callee = mod.functions.get(_tail(node.func) or "")
+        if callee is None or depth >= 4:
+            return False, filled  # handed to code we cannot see: unproven
+        params = [a.arg for a in callee.args.posonlyargs + callee.args.args]
+        for i, kwname in passed:
+            pname = kwname if kwname is not None else (
+                params[i] if i < len(params) else None
+            )
+            if pname is None:
+                return False, filled
+            clean, sub_filled = _dma_staging(mod, callee, pname, depth + 1)
+            if not clean:
+                return False, filled
+            filled |= sub_filled
+    return True, filled
 
 
 def _lint_file(path: Path, rel: str) -> list[Finding]:
@@ -248,6 +330,12 @@ def _lint_file(path: Path, rel: str) -> list[Finding]:
 
         for call in calls:
             kwargs = {kw.arg: kw.value for kw in call.keywords if kw.arg}
+            gspec = kwargs.get("grid_spec")
+            if isinstance(gspec, ast.Call):
+                for kw in gspec.keywords:
+                    if kw.arg:
+                        kwargs.setdefault(kw.arg, kw.value)
+            kernel = _resolve_kernel_fn(mod, call.args[0] if call.args else None)
             grid = kwargs.get("grid")
             out_specs = kwargs.get("out_specs")
             in_specs = kwargs.get("in_specs")
@@ -305,9 +393,19 @@ def _lint_file(path: Path, rel: str) -> list[Finding]:
                 if isinstance(scratch, (ast.List, ast.Tuple))
                 else ([scratch] if scratch is not None else [])
             )
-            for entry in entries:
+            # scratch refs are the kernel's trailing positional params
+            kparams = (
+                [a.arg for a in kernel.args.posonlyargs + kernel.args.args]
+                if kernel is not None else []
+            )
+            first = len(kparams) - len(entries)
+            for idx, entry in enumerate(entries):
                 if not (isinstance(entry, ast.Call) and _tail(entry.func) == "VMEM"):
                     continue
+                if kernel is not None and first + idx >= 0:
+                    clean, filled = _dma_staging(mod, kernel, kparams[first + idx])
+                    if clean and filled:
+                        continue  # DMA staging copy of an input, not an accumulator
                 if len(entry.args) < 2:
                     continue
                 dt = entry.args[1]
@@ -324,7 +422,6 @@ def _lint_file(path: Path, rel: str) -> list[Finding]:
                     )
 
             # the kernel body: trace-time branches + narrow dot accumulators
-            kernel = _resolve_kernel_fn(mod, call.args[0] if call.args else None)
             if kernel is None or kernel.name in linted_kernels:
                 continue
             linted_kernels.add(kernel.name)

@@ -64,8 +64,6 @@ def tp_matmul(
     absent/1-wide, when d does not divide ``tp_shards``, or when K is not
     divisible by ``tp_shards`` (chunk boundaries would straddle shards).
     """
-    from jax.experimental.shard_map import shard_map
-
     K = x.shape[-1]
     d = _axis_sizes(mesh).get(axis, 1)
     tp = schedule.tp_shards
@@ -109,9 +107,9 @@ def tp_matmul(
 
     x_spec = P(*([None] * (x.ndim - 1) + [axis]))
     w_spec = P(axis, None)
-    fn = shard_map(
-        body, mesh, in_specs=(x_spec, w_spec), out_specs=P(),
-        check_rep=False,
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(x_spec, w_spec), out_specs=P(),
+        check_vma=False,
     )
     return fn(x, w)
 

@@ -140,8 +140,8 @@ def decode_attention(
 
 def paged_attention(
     q: jax.Array,         # (B, H, D)
-    k_pool: jax.Array,    # (NB, bs, KV, D)
-    v_pool: jax.Array,    # (NB, bs, KV, D)
+    k_pool: jax.Array,    # (NB, KV, bs, Dp)
+    v_pool: jax.Array,    # (NB, KV, bs, Dp)
     pos_pool: jax.Array,  # (NB, bs)
     tables: jax.Array,    # (B, nblk)
     q_pos: jax.Array,     # (B,)

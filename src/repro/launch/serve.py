@@ -1,12 +1,17 @@
 """End-to-end serving driver.
 
 Runs the LLM-42 engine on a synthetic or ShareGPT-like workload with a mix
-of deterministic and non-deterministic requests, reporting throughput
-(simulated TPU-v5e time via the cost model + CPU wall time), rollback and
-recomputation statistics.
+of deterministic and non-deterministic requests on whatever device JAX
+finds, and reports the device, wall time, compiles, rollback and
+recomputation statistics.  ``--arch`` builds the published config at full
+width (random weights from ``--seed``); ``--smoke`` builds the reduced
+same-family config that runs on a CPU.  Lines marked "cost-model estimate
+(not measured)" replay the run's event log through the analytical TPU cost
+model (``serving.costmodel``); they are not timings.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
-      --requests 16 --det-ratio 0.25 --mode llm42
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve --smoke \
+      --arch tinyllama-1.1b --requests 16 --det-ratio 0.25 --mode llm42
+  PYTHONPATH=src python -m repro.launch.serve --arch phi3-mini-3.8b  # on a TPU
 """
 
 from __future__ import annotations
@@ -20,6 +25,7 @@ import numpy as np
 
 from repro import configs as config_registry
 from repro.core.determinism import FAST_PATH_POLICY, Mode
+from repro.launch import compile_cache
 from repro.models import init_params
 from repro.models.multimodal import audio_frames, vision_embeds
 from repro.serving import costmodel
@@ -84,9 +90,9 @@ def run_cluster(args, full_cfg, make_engine, reqs) -> None:
     print(f"cluster: {args.replicas} replicas, tp={args.tp}, "
           f"finished {len(done)} requests, {res.out_tokens} tokens "
           f"in {wall:.1f}s wall")
-    print(f"simulated v5e fleet time: {res.total_time * 1e3:.1f} ms "
-          f"-> {res.throughput:.0f} tok/s aggregate "
-          f"(goodput @ TTFT<=1s: {res.goodput(1.0):.0f} tok/s)")
+    print(f"cost-model estimate (not measured): fleet time "
+          f"{res.total_time * 1e3:.1f} ms -> {res.throughput:.0f} tok/s "
+          f"aggregate (goodput @ TTFT<=1s: {res.goodput(1.0):.0f} tok/s)")
     rt = cluster.router
     print(f"router: {rt.assignments} assignments, "
           f"affinity hit rate {100 * rt.affinity_hit_rate:.0f}%, "
@@ -108,12 +114,16 @@ def run_cluster(args, full_cfg, make_engine, reqs) -> None:
               f"{args.replicas} pids -> {args.trace_out}")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
-    ap.add_argument("--smoke", action="store_true", default=True,
-                    help="use the reduced config (CPU-runnable)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced same-family config (CPU-runnable);"
+                         " without it the published config runs at full"
+                         " width")
     ap.add_argument("--requests", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, default=32,
+                    help="prompt tokens per request (synthetic workload)")
     ap.add_argument("--det-ratio", type=float, default=0.25)
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--mode", default="llm42",
@@ -195,48 +205,76 @@ def main() -> None:
                          " log as JSONL (one provenance record per token:"
                          " committing schedule, verify window, n_match,"
                          " top-1/top-2 logit margin)")
-    args = ap.parse_args()
+    return ap
 
-    cfg = config_registry.get_smoke_config(args.arch)
+
+def make_engine(args, cfg, params, cost_cfg, **overrides) -> Engine:
+    """The engine ``main`` serves with, from parsed ``args``; keyword
+    overrides replace single Engine arguments (e.g. ``max_batch``)."""
+    kw = dict(
+        mode=Mode(args.mode), policy=FAST_PATH_POLICY,
+        window=args.window, group=args.group, max_batch=args.max_batch,
+        capacity=min(cfg.max_seq_len, 512),
+        scheduler={
+            "default": None,
+            "overlap": OverlapPolicy(),
+            "pause": PauseDecodePolicy(),
+            "adaptive": AdaptivePolicy(),
+        }[args.scheduler],
+        spec_depth=args.spec_depth,
+        verify_latency_ms=args.verify_latency_ms,
+        cost_cfg=cost_cfg,  # deadlines priced at the full model's scale
+        prefill_chunk=args.prefill_chunk,
+        block_size=args.block_size,
+        num_blocks=args.num_blocks,
+        prefix_cache=(args.prefix_cache == "on"),
+        trace=args.trace_out is not None,
+        audit=args.audit_out is not None,
+        tp=args.tp,
+    )
+    kw.update(overrides)
+    return Engine(cfg, params, **kw)
+
+
+def device_line() -> str:
+    devs = jax.devices()
+    return (f"device: platform={devs[0].platform} "
+            f"kind={devs[0].device_kind} count={len(devs)}")
+
+
+def main(argv=None):
+    """Serve one workload; returns ``(engine, finished_requests)``."""
+    args = build_parser().parse_args(argv)
+    cache_dir = compile_cache.enable()
+    stats = compile_cache.stats()
+    print(device_line())
+    print(f"compile cache: {cache_dir}")
+
     full_cfg = config_registry.get_config(args.arch)
+    cfg = (config_registry.get_smoke_config(args.arch) if args.smoke
+           else full_cfg)
     print(f"arch={cfg.name} mode={args.mode} n={args.requests} "
           f"det_ratio={args.det_ratio}")
-    params = init_params(cfg, jax.random.key(0))
+    params = init_params(cfg, jax.random.key(args.seed))
+    leaves = jax.tree_util.tree_leaves(params)
+    print(f"params: {sum(x.size for x in leaves) / 1e9:.3f}B parameters, "
+          f"{sum(x.nbytes for x in leaves) / 1e9:.3f} GB ({cfg.dtype})")
 
-    def make_engine(idx: int = 0) -> Engine:
-        return Engine(
-            cfg, params, mode=Mode(args.mode), policy=FAST_PATH_POLICY,
-            window=args.window, group=args.group, max_batch=args.max_batch,
-            capacity=min(cfg.max_seq_len, 512),
-            scheduler={
-                "default": None,
-                "overlap": OverlapPolicy(),
-                "pause": PauseDecodePolicy(),
-                "adaptive": AdaptivePolicy(),
-            }[args.scheduler],
-            spec_depth=args.spec_depth,
-            verify_latency_ms=args.verify_latency_ms,
-            cost_cfg=full_cfg,  # deadlines priced at the full model's scale
-            prefill_chunk=args.prefill_chunk,
-            block_size=args.block_size,
-            num_blocks=args.num_blocks,
-            prefix_cache=(args.prefix_cache == "on"),
-            trace=args.trace_out is not None,
-            audit=args.audit_out is not None,
-            tp=args.tp,
-        )
+    def engine_for(idx: int = 0) -> Engine:
+        return make_engine(args, cfg, params, full_cfg)
 
     reqs = build_requests(cfg, args.requests, args.det_ratio, args.max_new,
-                          args.seed, args.workload)
+                          args.seed, args.workload, in_len=args.prompt_len)
 
     if args.replicas > 1:
-        run_cluster(args, full_cfg, make_engine, reqs)
-        return
+        run_cluster(args, full_cfg, engine_for, reqs)
+        return None, None
 
-    engine = make_engine()
+    engine = engine_for()
     for r in reqs:
         engine.submit(r)
-    t0 = time.time()
+    snap = stats.snapshot()
+    t0 = time.perf_counter()
     if args.metrics_interval > 0:
         done = None
         for it in range(1, 100001):
@@ -253,7 +291,8 @@ def main() -> None:
         assert done is not None, "engine did not drain"
     else:
         done = engine.run()
-    wall = time.time() - t0
+    jax.block_until_ready(engine.pool.data)
+    wall = time.perf_counter() - t0
 
     out_tokens = sum(r.num_output for r in done)
     rollbacks = sum(r.num_rollbacks for r in done)
@@ -264,7 +303,8 @@ def main() -> None:
         invariant_mode=(args.mode == "batch_invariant"),
     )
     print(f"finished {len(done)} requests, {out_tokens} tokens "
-          f"in {wall:.1f}s wall")
+          f"in {wall:.3f} s wall on {jax.devices()[0].platform}, "
+          f"compile included: {stats.since(snap)}")
     print(f"rollbacks={rollbacks} recomputed_tokens={recomputed} "
           f"({100.0 * recomputed / max(out_tokens, 1):.2f}%)")
     print(f"speculation pipeline: depth limit {args.spec_depth}, "
@@ -293,7 +333,7 @@ def main() -> None:
         engine.runtime.makespan
         if args.verify_latency_ms is not None else sim["total_s"]
     )
-    print(f"simulated v5e time: {total_s * 1e3:.1f} ms "
+    print(f"cost-model estimate (not measured): {total_s * 1e3:.1f} ms "
           f"-> {out_tokens / total_s:.0f} tok/s "
           f"(decode {sim.get('decode_s', 0) * 1e3:.1f} ms, "
           f"verify {sim.get('verify_s', 0) * 1e3:.1f} ms, "
@@ -302,7 +342,8 @@ def main() -> None:
           f"{100.0 * sim.get('verify_occupancy', 0):.0f}%)")
     if args.verify_latency_ms is not None:
         rt = engine.runtime
-        print(f"stream clocks: main {rt.main.now * 1e3:.1f} ms, "
+        print(f"cost-model stream clocks (not measured): "
+              f"main {rt.main.now * 1e3:.1f} ms, "
               f"verify backlog {rt.verify_backlog * 1e3:.2f} ms, "
               f"makespan {rt.makespan * 1e3:.1f} ms")
 
@@ -328,6 +369,11 @@ def main() -> None:
         print(f"audit: {len(audit.records)} provenance records "
               f"({len(done)} requests, every committed token covered) "
               f"-> {args.audit_out}")
+    mem = jax.devices()[0].memory_stats()
+    if mem:
+        print(f"device memory: peak {mem.get('peak_bytes_in_use', 0) / 1e9:.3f} GB"
+              f" of {mem.get('bytes_limit', 0) / 1e9:.3f} GB")
+    return engine, done
 
 
 if __name__ == "__main__":

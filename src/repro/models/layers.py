@@ -287,7 +287,7 @@ def attention_paged(
     p: Dict,
     cfg,
     x: jax.Array,  # (B, W, D)
-    cache: Dict,  # {"k","v": (NB+2, bs, KV, HD), "pos": (NB+2, bs)} pool-shaped
+    cache: Dict,  # {"k","v": (NB+2, KV, bs, HDp), "pos": (NB+2, bs)} pool-shaped
     tables: jax.Array,  # (B, nblk) int32 block ids, -1 = unallocated
     start_pos: jax.Array,  # (B,) absolute position of x[:, 0]
     schedule: Schedule,
@@ -295,18 +295,21 @@ def attention_paged(
 ) -> Tuple[jax.Array, Dict]:
     """Incremental attention reading/writing K/V *through the block table*.
 
-    The pool leaves carry no batch axis; each row's view is the
-    concatenation of its table's blocks (``-1`` entries read the null block,
-    whose positions are ``-1`` and therefore always masked).  Writes for the
-    W new tokens go to ``tables[b, abs_pos // block_size]``; positions past
-    the table (padded rows / padded window tails) are absorbed by the
-    scratch block, which is never read.  Semantically — and bitwise — this
-    equals gathering the view and running :func:`attention_cached` on it;
-    the host-side gather copy is what disappears.
+    The pool leaves carry no batch axis and store each block head-major
+    with the head dim padded to ``HDp`` lanes (``serving.blockpool``); each
+    row's view is the concatenation of its table's blocks (``-1`` entries
+    read the null block, whose positions are ``-1`` and therefore always
+    masked).  Writes for the W new tokens go to
+    ``tables[b, abs_pos // block_size]``; positions past the table (padded
+    rows / padded window tails) are absorbed by the scratch block, which is
+    never read.  Semantically — and bitwise — this equals gathering the
+    view and running :func:`attention_cached` on it; the host-side gather
+    copy is what disappears.
     """
     B, W, _ = x.shape
     bs = paged.block_size
     nblk = tables.shape[1]
+    hd, hdp = cfg.hd, cache["k"].shape[-1]
     q, k_new, v_new = _qkv(p, cfg, x, schedule)
     abs_pos = start_pos[:, None] + jnp.arange(W)[None, :]  # (B, W)
     q = rope(q, abs_pos, cfg.rope_theta)
@@ -316,16 +319,21 @@ def attention_paged(
     off = abs_pos % bs
     bid = jnp.take_along_axis(tables, jnp.clip(blk, 0, nblk - 1), axis=1)
     bid = jnp.where((bid < 0) | (blk >= nblk), paged.scratch_bid, bid)
-    k_cache = cache["k"].at[bid, off].set(k_new.astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bid, off].set(v_new.astype(cache["v"].dtype))
+    # (B, W, 1) x (KV,) x (B, W, 1) index -> one (block, head, slot) row each
+    heads = jnp.arange(k_new.shape[2])
+    at = (bid[..., None], heads, off[..., None])
+    lanes = [(0, 0)] * 3 + [(0, hdp - hd)]
+    k_cache = cache["k"].at[at].set(jnp.pad(k_new, lanes).astype(cache["k"].dtype))
+    v_cache = cache["v"].at[at].set(jnp.pad(v_new, lanes).astype(cache["v"].dtype))
     pos_cache = cache["pos"].at[bid, off].set(abs_pos)
 
     if W == 1 and ops.on_tpu() and cfg.logit_softcap == 0:
         # single-token decode on TPU: the table-walking Pallas kernels
         # (commit single-pass vs `# det: fastpath` split variant, selected
-        # by the schedule) read K/V in place — the (B, nblk*bs, ...) view
-        # gather below never materializes.  The dispatcher scales q by
-        # hd^-0.5 itself, so it gets the unscaled roped q.
+        # by the schedule) DMA only the table's blocks from HBM — the
+        # (B, nblk*bs, ...) view gather below never materializes.  The
+        # dispatcher scales q by hd^-0.5 itself, so it gets the unscaled
+        # roped q.
         out = ops.paged_attention(
             q[:, 0], k_cache, v_cache, pos_cache, tables, abs_pos[:, 0],
             schedule, null_bid=paged.null_bid,
@@ -335,12 +343,16 @@ def attention_paged(
 
     q = q * (cfg.hd**-0.5)
     flat = jnp.where(tables < 0, paged.null_bid, tables)  # (B, nblk)
-    k_view = k_cache[flat].reshape(B, nblk * bs, -1, cfg.hd)
-    v_view = v_cache[flat].reshape(B, nblk * bs, -1, cfg.hd)
+
+    def view(leaf):  # (B, nblk, KV, bs, HDp) -> (B, nblk*bs, KV, hd)
+        v = jnp.swapaxes(leaf[flat][..., :hd], 2, 3)
+        return v.reshape(B, nblk * bs, -1, hd)
+
     kp = pos_cache[flat].reshape(B, 1, nblk * bs)  # (B, 1, S)
     qp = abs_pos[:, :, None]  # (B, W, 1)
     mask = (kp >= 0) & (kp <= qp)
-    out = _softmax_attend(q, k_view, v_view, mask, schedule, cfg.logit_softcap)
+    out = _softmax_attend(q, view(k_cache), view(v_cache), mask, schedule,
+                          cfg.logit_softcap)
     out = matmul(out.reshape(B, W, -1).astype(x.dtype), p["wo"], schedule)
     return out, {"k": k_cache, "v": v_cache, "pos": pos_cache}
 

@@ -38,6 +38,14 @@ Recurrent O(1) state (mamba/rwkv), sliding-window rings (bounded at
 layout — paging buys nothing for constant-size state; :func:`build_layout`
 classifies every cache leaf once, by shape, into ``slot`` vs ``paged``.
 
+K/V leaves are stored *head-major* inside a block, ``(num_blocks + 2,
+KV, block_size, head_dim_padded)``: one (block, head) pair is then a
+contiguous ``(block_size, head_dim)`` tile, which is what the paged
+attention kernel DMAs from HBM.  The head dim is padded up to a multiple
+of ``default_lanes()`` (128 on TPU, whose HBM layout pads it to whole lane tiles
+anyway, so the padding costs no memory there; 1 elsewhere).  Padding
+lanes are written as zeros and sliced away by every reader.
+
 Freed blocks are wiped (``pos`` leaves back to -1) before they can be
 reallocated: a stale absolute position *smaller* than a new owner's query
 position would otherwise mask garbage keys into attention.  (Stale
@@ -77,6 +85,8 @@ class LeafDesc:
 
     kind: str  # "slot" (dense per-slot) | "paged" (block-cut)
     axis: int  # batch axis (paged: capacity axis is axis + 1)
+    head_dim: int = 0  # paged K/V leaves: logical head dim (0 = pos leaf)
+    head_dim_padded: int = 0  # ... and its stored, lane-padded width
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,11 +116,17 @@ class Layout:
         return self.blocks_per_table * self.block_size
 
 
+def default_lanes() -> int:
+    """Lane width the stored K/V head dim is padded to on this backend."""
+    return 128 if jax.default_backend() == "tpu" else 1
+
+
 def build_layout(
     cfg: ModelConfig, capacity: int, block_size: int, num_blocks: int
 ) -> Layout:
     """Classify every cache leaf by shape (sentinel batch/capacity dims)."""
     assert block_size >= 1
+    lanes = default_lanes()
     spec = cache_spec(cfg, _SENT_B, _SENT_C)
 
     def classify(s: jax.ShapeDtypeStruct) -> LeafDesc:
@@ -120,7 +136,12 @@ def build_layout(
         if not c:
             return LeafDesc("slot", b[0])
         assert c == [b[0] + 1], f"capacity axis must follow batch in {s.shape}"
-        return LeafDesc("paged", b[0])
+        tail = s.shape[b[0] + 2:]
+        if not tail:
+            return LeafDesc("paged", b[0])
+        assert len(tail) == 2, f"paged K/V leaf must end in (KV, hd): {s.shape}"
+        hd = tail[1]
+        return LeafDesc("paged", b[0], hd, -(-hd // lanes) * lanes)
 
     axes = jax.tree_util.tree_map(classify, spec)
     has_paged = any(
@@ -144,13 +165,15 @@ def init_cache(cfg: ModelConfig, lay: Layout, num_slots: int) -> Any:
             shape = tuple(
                 num_slots + 1 if d == _SENT_B else d for d in s.shape
             )
+        elif desc.head_dim:
+            ax = desc.axis
+            shape = s.shape[:ax] + (
+                lay.num_blocks + 2, s.shape[ax + 2], lay.block_size,
+                desc.head_dim_padded,
+            )
         else:
             ax = desc.axis
-            shape = (
-                s.shape[:ax]
-                + (lay.num_blocks + 2, lay.block_size)
-                + s.shape[ax + 2:]
-            )
+            shape = s.shape[:ax] + (lay.num_blocks + 2, lay.block_size)
         if s.dtype == jnp.int32:
             return jnp.full(shape, -1, s.dtype)  # pos slots start empty
         return jnp.zeros(shape, s.dtype)
@@ -161,6 +184,24 @@ def init_cache(cfg: ModelConfig, lay: Layout, num_slots: int) -> Any:
 # ---------------------------------------------------------------------------
 # device gather / scatter through block tables
 # ---------------------------------------------------------------------------
+
+
+def _to_blocks(u: jax.Array, desc: LeafDesc) -> jax.Array:
+    """Token-major ``(..., N, bs, KV, hd)`` -> stored ``(..., N, KV, bs, hdp)``."""
+    if not desc.head_dim:
+        return u
+    u = jnp.swapaxes(u, desc.axis + 1, desc.axis + 2)
+    pad = desc.head_dim_padded - desc.head_dim
+    if pad:
+        u = jnp.pad(u, [(0, 0)] * (u.ndim - 1) + [(0, pad)])
+    return u
+
+
+def _from_blocks(x: jax.Array, desc: LeafDesc) -> jax.Array:
+    """Inverse of :func:`_to_blocks`: drop the pad lanes, token-major again."""
+    if not desc.head_dim:
+        return x
+    return jnp.swapaxes(x[..., : desc.head_dim], desc.axis + 1, desc.axis + 2)
 
 
 def gather(pool: Any, lay: Layout, slots: jax.Array, tables: jax.Array) -> Any:
@@ -175,9 +216,9 @@ def gather(pool: Any, lay: Layout, slots: jax.Array, tables: jax.Array) -> Any:
         ax = desc.axis
         if desc.kind == "slot":
             return jnp.take(leaf, slots, axis=ax)
-        out = jnp.take(leaf, flat, axis=ax)  # (..., B*nblk, bs, ...)
-        shape = leaf.shape[:ax] + (B, nblk * lay.block_size) + leaf.shape[ax + 2:]
-        return out.reshape(shape)
+        out = _from_blocks(jnp.take(leaf, flat, axis=ax), desc)
+        return out.reshape(out.shape[:ax] + (B, nblk * lay.block_size)
+                           + out.shape[ax + 2:])
 
     return jax.tree_util.tree_map(g, pool, lay.axes)
 
@@ -198,10 +239,10 @@ def scatter(
             idx = (slice(None),) * ax + (slots,)
             return leaf.at[idx].set(u.astype(leaf.dtype))
         u2 = u.reshape(
-            leaf.shape[:ax] + (B * nblk, lay.block_size) + leaf.shape[ax + 2:]
+            u.shape[:ax] + (B * nblk, lay.block_size) + u.shape[ax + 2:]
         )
         idx = (slice(None),) * ax + (flat,)
-        return leaf.at[idx].set(u2.astype(leaf.dtype))
+        return leaf.at[idx].set(_to_blocks(u2, desc).astype(leaf.dtype))
 
     return jax.tree_util.tree_map(s, pool, lay.axes, update)
 

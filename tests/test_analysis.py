@@ -60,6 +60,16 @@ def test_fixture_bf16_accum_flagged():
     assert {f.where.split("::")[1] for f in accum} == {"gemm_bf16_accum", "_kernel"}
 
 
+def test_fixture_prefetch_grid_spec_flagged():
+    """Specs inside ``grid_spec=`` are linted; a DMA-only staging buffer
+    keeps its input dtype without an accum-dtype finding."""
+    fs = kernel_lint.run_pass(
+        REPO, files=[_fixture("fixture_prefetch_grid_spec.py")]
+    )
+    rules = sorted(f.rule for f in fs)
+    assert rules == ["accum-dtype", "grid-reduction-extent"], fs
+
+
 def test_fixture_splitk_commit_flagged():
     fs = taint.scan_files(
         [_fixture("fixture_splitk_commit.py")], REPO, expected_roots=frozenset()

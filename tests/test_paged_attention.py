@@ -54,13 +54,16 @@ def _model(arch="llama3-8b"):
 
 
 def _rand_problem(seed, *, B=3, H=4, KV=2, D=8, NB=20, bs=4, nblk=5,
-                  dtype=jnp.float32):
-    """Random pool + tables; the last two pool blocks are null/scratch."""
+                  dtype=jnp.float32, Dp=None):
+    """Random head-major pool + tables; the last two pool blocks are
+    null/scratch.  ``Dp > D`` pads the stored head dim with junk lanes
+    that every reader must ignore."""
     rng = np.random.default_rng(seed)
     null_bid, scratch_bid = NB - 2, NB - 1
+    Dp = D if Dp is None else Dp
     q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
-    k = jnp.asarray(rng.standard_normal((NB, bs, KV, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((NB, bs, KV, D)), dtype)
+    k = jnp.asarray(rng.standard_normal((NB, KV, bs, Dp)), dtype)
+    v = jnp.asarray(rng.standard_normal((NB, KV, bs, Dp)), dtype)
     # null block: positions -1 (always masked), zero K/V
     k = k.at[null_bid].set(0.0)
     v = v.at[null_bid].set(0.0)
@@ -203,6 +206,25 @@ class TestFusedStepBitwiseIdentity:
         base, _ = _run(cfg, params, paged=False, block_size=block_size)
         got, _ = _run(cfg, params, paged=True, block_size=block_size)
         assert got == base, block_size
+
+    def test_lane_padded_pool_identity(self, monkeypatch):
+        """The TPU pool layout (head dim padded to 128 lanes) commits the
+        same streams as the unpadded CPU layout: pad lanes are written as
+        zeros and never read."""
+        from repro.serving import blockpool
+
+        cfg, params = _model()
+        base, _ = _run(cfg, params, paged=True)
+        monkeypatch.setattr(blockpool, "default_lanes", lambda: 128)
+        got, eng = _run(cfg, params, paged=True)
+        k = next(
+            leaf for leaf, d in zip(
+                jax.tree_util.tree_leaves(eng.pool.data),
+                jax.tree_util.tree_leaves(eng.pool.layout.axes),
+            ) if d.head_dim
+        )
+        assert k.shape[-1] == 128 and cfg.hd < 128
+        assert got == base
 
     def test_recurrent_arch_identity(self):
         """Hybrid (attn + mamba + MoE) engine: the fused step threads the

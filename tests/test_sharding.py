@@ -30,10 +30,9 @@ from repro.serving.scheduler import (
 
 
 def _mesh(multi=False):
-    # jax 0.4.37 AbstractMesh signature: a tuple of (axis_name, size) pairs
     if multi:
-        return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-    return AbstractMesh((("data", 16), ("model", 16)))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 class TestSpecFor:
