@@ -141,7 +141,8 @@ def make_verify_body(
 
     if not collect:
 
-        def verify_attn(
+        @jax.named_scope("verify")
+        def verify_step(
             params: Dict,
             pool: Any,
             slots: jax.Array,  # (G,) pool slots (scratch slot for pad rows)
@@ -168,9 +169,10 @@ def make_verify_body(
             pool = _writeback(pool, slots, tables, new_cache)
             return pool, n_match, commit_tok, v_tokens, margins
 
-        return verify_attn
+        return verify_step
 
-    def verify_recurrent(
+    @jax.named_scope("verify")
+    def verify_step(
         params: Dict,
         pool: Any,
         anchor: Any,  # state-pool replay-anchor tree
@@ -203,7 +205,7 @@ def make_verify_body(
         )
         v_tokens = sample_window(logits, seeds, out_base, temps, top_ks)
         n_match, commit_tok = _match_and_commit(v_tokens, cand, cand_len, W)
-        margins = top2_margin(logits)  # audit provenance, see verify_attn
+        margins = top2_margin(logits)  # audit provenance, as above
 
         # per_pos[j] = state after consuming inputs[j]
         commit_rows = statepool.select_index(per_pos, n_match)
@@ -217,7 +219,7 @@ def make_verify_body(
         pool = _writeback(pool, slots, tables, new_cache)
         return pool, anchor, commit_rows, n_match, commit_tok, v_tokens, margins
 
-    return verify_recurrent
+    return verify_step
 
 
 # det: commit-path

@@ -159,6 +159,7 @@ def paged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), F32),
         interpret=interpret,
+        name="paged_attention",
     )(tab, qp, qg, k_pool, v_pool, pv)
     return out.reshape(B, KV * G, D)
 
@@ -279,5 +280,6 @@ def paged_attention_fast(
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), F32),
         interpret=interpret,
+        name="paged_attention_fast",
     )(tab, qp, qg, k_pool, v_pool, pv)
     return out.reshape(B, KV * G, D)

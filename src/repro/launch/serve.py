@@ -192,8 +192,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="export a Chrome/Perfetto trace-event JSON of the"
-                         " run (per-request lifecycle spans + main/verify"
-                         " stream pass slices; load in ui.perfetto.dev or"
+                         " run (host spans of every engine phase, protocol"
+                         " instants, one track per request, on the wall"
+                         " clock; load in ui.perfetto.dev or"
                          " chrome://tracing)")
     ap.add_argument("--metrics-interval", type=int, default=0, metavar="N",
                     help="print a metrics-snapshot line every N engine"

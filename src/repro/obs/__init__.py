@@ -1,20 +1,23 @@
-"""Unified observability layer (ISSUE 9): tracing + metrics + audit.
+"""Unified observability layer: spans, tracing, metrics and audit.
 
-One bundle, three concerns, one wiring point (``Engine(trace=..., audit=...)``):
+One bundle, one wiring point (``Engine(trace=..., audit=...)``):
 
 * :mod:`repro.obs.metrics` — the typed metrics registry every subsystem
   registers into; always on (pure host bookkeeping), ``snapshot()`` is the
-  single source of truth ``mem_stats()`` and the benchmarks now read.
-* :mod:`repro.obs.trace` — dual-stream request tracing on the engine's
-  stream clocks, exported as Chrome/Perfetto trace-event JSON
-  (``serve.py --trace-out``).  Off by default (:class:`NullTracer`).
+  single source of truth ``mem_stats()`` and the benchmarks read.
+* :mod:`repro.obs.trace` — host spans (``Observability.span``) on the obs
+  clock, the wall clock the JAX profiler stamps host events with: each
+  span is a profiler ``TraceAnnotation``, two always-on registry counters
+  (``span.<name>.s`` / ``.n``) and, with the tracer on, a slice of the
+  Chrome/Perfetto export (``serve.py --trace-out``).  The tracer is off by
+  default (:class:`NullTracer`).
 * :mod:`repro.obs.audit` — the per-committed-token determinism audit log
   (``serve.py --audit-out``).  Off by default (:class:`NullAudit`).
 
 Everything here is observer-effect-free by construction: recorders are
 host-side, device programs are identical with recording on or off, and
 ``tests/test_obs.py`` proves committed streams bitwise-identical across
-the on/off matrix.
+the on/off matrix, the profiler included.
 """
 
 from __future__ import annotations
@@ -27,7 +30,13 @@ from repro.obs.metrics import (
     Histogram,
     MetricsRegistry,
 )
-from repro.obs.trace import NullTracer, Tracer, validate_chrome_trace
+from repro.obs.trace import (
+    NullTracer,
+    Span,
+    Tracer,
+    now_ns,
+    validate_chrome_trace,
+)
 
 
 class Observability:
@@ -43,6 +52,21 @@ class Observability:
         self.metrics = registry if registry is not None else MetricsRegistry()
         self.tracer = Tracer() if trace else NullTracer()
         self.audit = AuditLog() if audit else NullAudit()
+        self._open: list = []  # spans entered and not yet left, outermost first
+        self._span_counters: dict = {}
+
+    def span(self, name: str) -> Span:
+        """A host span for a ``with`` block (:mod:`repro.obs.trace`)."""
+        counters = self._span_counters.get(name)
+        if counters is None:
+            m = self.metrics
+            counters = self._span_counters[name] = (
+                m.counter(f"span.{name}.s", unit="s",
+                          help=f"seconds inside {name} (obs clock)"),
+                m.counter(f"span.{name}.n", unit="spans",
+                          help=f"times {name} was entered"),
+            )
+        return Span(self, name, counters)
 
 
 __all__ = [
@@ -55,7 +79,9 @@ __all__ = [
     "NullAudit",
     "NullTracer",
     "Observability",
+    "Span",
     "TokenProvenance",
     "Tracer",
+    "now_ns",
     "validate_chrome_trace",
 ]

@@ -1,100 +1,130 @@
-"""Dual-stream request tracing with Chrome/Perfetto trace-event export.
+"""Host spans on the profiler's clock, and request tracing exported as
+Chrome/Perfetto trace-event JSON.
 
-Spans are recorded against the engine's :class:`DualClockRuntime` timeline
-— the same clock that decides verdict deadlines — so a trace shows exactly
-what the scheduler saw: decode/prefill passes on the **main** stream row,
-deferred verification on the **verify** stream row (queueing, backlog and
-all), protocol instants (window submit, commit, rollback, preempt,
-restore) on a third row, and one async track per request spanning
-submit → retire.
+**The obs clock.** :func:`now_ns` is wall time in nanoseconds since the
+epoch (``time.time_ns``): the clock the JAX profiler stamps its host
+events with.  An ``.xplane.pb`` stores them relative to the session's
+``profile_start_time`` (a stat of its ``Task Environment`` plane), so
+``profile_start_time + event.start_ns`` is an obs-clock stamp
+(``tests/test_obs.py`` checks this against a CPU profile).
 
-Two timing modes, matching the runtime's:
+**Spans.** ``with obs.span("engine.plan"):`` (:class:`Span`, opened
+through ``Observability.span``) does three things:
 
-* **costed clock** — every pass has real ``(start, finish)`` stream times
-  (``ExecStream.launch``); the runtime stashes the last span per stream
-  (``last_main_span`` / ``last_verify_span``) and the engine hands it to
-  :meth:`Tracer.pass_span` verbatim.
-* **logical clock** — passes have no duration (the clock ticks once per
-  iteration), so ``pass_span`` receives ``span=None`` and the tracer
-  defers layout: at :meth:`end_iteration` the iteration's pending passes
-  are laid out sequentially across the iteration window ``[t0, t1]``.
-  Relative widths are synthetic; ordering, stream attribution and nesting
-  are real.
+* opens ``jax.profiler.TraceAnnotation(name)``, so the span lands in any
+  profiler trace on the same clock as the device's ops;
+* adds its seconds and 1 to the registry counters ``span.<name>.s`` and
+  ``span.<name>.n`` (always on: two clock reads and two adds);
+* when the :class:`Tracer` is on, records a slice on the ``host`` row
+  with the same start and end.
 
-A fused mixed-batch launch (``Engine._fused_step``) renders as ONE parent
-``fused_step`` slice on the main row with its sub-passes nested inside:
-the engine brackets the sub-pass bookkeeping with ``begin_group`` /
-``end_group`` and the tracer emits a parent span covering the min/max
-envelope of the group's children.
+Spans nest as the ``with`` blocks do.  A span's self time is its
+duration minus what its child spans cover (:attr:`Span.self_s`).
 
-Export is the Chrome trace-event JSON format (the ``traceEvents`` array
-form) — loadable in Perfetto (https://ui.perfetto.dev) or
-``chrome://tracing``:
+**Tracer export** (``serve.py --trace-out``): ``"ph": "X"`` slices of the
+host spans (``ts``/``dur`` in whole microseconds of the obs clock),
+``"ph": "i"`` protocol instants (submit, admit, verify_submit, commit,
+rollback, preempt, restore, retire) on the ``protocol`` row,
+``"ph": "b"``/``"e"`` one async track per request from submit to retire,
+and ``"ph": "M"`` metadata naming the process and rows.  Stream-clock
+values the scheduler acts on (a verdict's ``ready_at``) ride as instant
+arguments.  :func:`validate_chrome_trace` is the schema gate every
+export passes.
 
-* ``"ph": "X"`` complete slices for passes (``ts``/``dur`` in µs),
-* ``"ph": "i"`` instants for protocol events,
-* ``"ph": "b"``/``"e"`` async begin/end per request lifecycle,
-* ``"ph": "M"`` metadata naming the process and the stream rows.
-
-:func:`validate_chrome_trace` is the schema gate CI runs on every exported
-trace: required fields per phase type, non-negative µs clocks, per-row
-monotonicity, and proper slice nesting (no partial overlap within a row).
-
-The tracer is host-side bookkeeping only — it never changes what the
-engine launches, so committed streams are bitwise identical with tracing
-on or off (``tests/test_obs.py`` proves it property-style).  When tracing
-is off the engine holds a :class:`NullTracer` whose methods are no-ops
-behind a single ``enabled`` flag check.
+Recording is host-side only: device programs are the same with spans,
+the tracer or the profiler on or off, so committed streams are bitwise
+identical (``tests/test_obs.py``).  With tracing off the engine holds a
+:class:`NullTracer`.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+import time
+from typing import Any, Dict, List, Tuple
 
-#: thread-id (row) assignment: one process, three fixed rows + per-request
-#: async tracks (async events carry their own ids, not tids)
-TID_MAIN = 0
-TID_VERIFY = 1
-TID_PROTOCOL = 2
+import jax
 
-_TID_FOR_STREAM = {"main": TID_MAIN, "verify": TID_VERIFY,
-                   "protocol": TID_PROTOCOL}
-_THREAD_NAMES = {TID_MAIN: "main stream", TID_VERIFY: "verify stream",
-                 TID_PROTOCOL: "protocol"}
+#: rows of one process; per-request async tracks carry their own ids
+TID_HOST = 0
+TID_PROTOCOL = 1
+_THREAD_NAMES = {TID_HOST: "host", TID_PROTOCOL: "protocol"}
 
-_US = 1e6  # stream-clock seconds/ticks -> trace microseconds
+
+def now_ns() -> int:
+    """The obs clock: the JAX profiler's host clock, wall ns since the
+    epoch."""
+    return time.time_ns()
+
+
+def _us(t_ns: int) -> int:
+    """Whole microseconds, floored: a child's slice stays inside its
+    parent's and adjacent slices never overlap."""
+    return t_ns // 1000
+
+
+class Span:
+    """One timed host phase; see the module docstring.  ``start_ns`` /
+    ``end_ns`` are obs-clock stamps, ``child_ns`` the time covered by the
+    spans opened directly inside it."""
+
+    __slots__ = ("name", "start_ns", "end_ns", "child_ns", "_obs", "_ann",
+                 "_counters")
+
+    def __init__(self, obs, name: str, counters) -> None:
+        self.name = name
+        self._obs = obs
+        self._counters = counters
+        self.start_ns = self.end_ns = self.child_ns = 0
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e9
+
+    @property
+    def self_s(self) -> float:
+        return (self.end_ns - self.start_ns - self.child_ns) / 1e9
+
+    def __enter__(self) -> "Span":
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._obs._open.append(self)
+        self.start_ns = now_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        # the wall clock may step back: a span never lasts less than 0
+        self.end_ns = end = max(now_ns(), self.start_ns)
+        self._ann.__exit__(*exc)
+        open_ = self._obs._open
+        open_.pop()
+        dur = end - self.start_ns
+        if open_:
+            open_[-1].child_ns += dur
+        secs, count = self._counters
+        secs.inc(dur / 1e9)
+        count.inc()
+        tr = self._obs.tracer
+        if tr.enabled:
+            tr.slice(self.name, self.start_ns, end,
+                     self_us=_us(dur - self.child_ns))
 
 
 class NullTracer:
-    """No-op recorder: one attribute read per call site, zero allocation."""
+    """No-op recorder: one attribute read per call site."""
 
     enabled = False
 
-    def begin_iteration(self, it: int, t0: float) -> None:
+    def slice(self, name: str, start_ns: int, end_ns: int,
+              **args: Any) -> None:
         pass
 
-    def end_iteration(self, t1: float) -> None:
+    def instant(self, name: str, **args: Any) -> None:
         pass
 
-    def pass_span(self, stream: str, name: str,
-                  span: Optional[Tuple[float, float]],
-                  args: Optional[Dict[str, Any]] = None) -> None:
+    def request_begin(self, rid: int, t_ns: int) -> None:
         pass
 
-    def instant(self, name: str, t: float, stream: str = "protocol",
-                **args: Any) -> None:
-        pass
-
-    def request_begin(self, rid: int, t: float) -> None:
-        pass
-
-    def request_end(self, rid: int, t: float) -> None:
-        pass
-
-    def begin_group(self, name: str, **args: Any) -> None:
-        pass
-
-    def end_group(self) -> None:
+    def request_end(self, rid: int, t_ns: int) -> None:
         pass
 
     def to_chrome_trace(
@@ -107,111 +137,25 @@ class Tracer(NullTracer):
     enabled = True
 
     def __init__(self) -> None:
-        #: finished slices: (name, tid, start, end, args)
-        self._spans: List[Tuple[str, int, float, float, Dict[str, Any]]] = []
-        #: instants: (name, tid, t, args)
-        self._instants: List[Tuple[str, int, float, Dict[str, Any]]] = []
-        #: async request events: (ph, rid, t)
-        self._asyncs: List[Tuple[str, int, float]] = []
-        #: passes awaiting layout: (stream, name, span|None, args, group_id)
-        self._pending: List[Tuple[str, str, Optional[Tuple[float, float]],
-                                  Dict[str, Any], int]] = []
-        self._groups: Dict[int, Tuple[str, Dict[str, Any]]] = {}
-        self._group_id = 0
-        self._open_group: Optional[int] = None
-        self._t0 = 0.0
-        self._it = 0
+        #: host slices: (name, start_ns, end_ns, args)
+        self.slices: List[Tuple[str, int, int, Dict[str, Any]]] = []
+        #: instants: (name, t_ns, args)
+        self._instants: List[Tuple[str, int, Dict[str, Any]]] = []
+        #: async request events: (ph, rid, t_ns)
+        self._asyncs: List[Tuple[str, int, int]] = []
 
-    # -- iteration protocol --------------------------------------------
+    def slice(self, name: str, start_ns: int, end_ns: int,
+              **args: Any) -> None:
+        self.slices.append((name, start_ns, end_ns, args))
 
-    def begin_iteration(self, it: int, t0: float) -> None:
-        self._it = it
-        self._t0 = float(t0)
+    def instant(self, name: str, **args: Any) -> None:
+        self._instants.append((name, now_ns(), args))
 
-    def end_iteration(self, t1: float) -> None:
-        """Lay out the iteration's pending passes.  Spans that arrived
-        with explicit stream times pass through; logical-clock spans
-        (``span=None``) divide the iteration window ``[t0, t1]`` equally,
-        in record order."""
-        self._flush(float(t1))
+    def request_begin(self, rid: int, t_ns: int) -> None:
+        self._asyncs.append(("b", rid, t_ns))
 
-    def _flush(self, t1: float) -> None:
-        if not self._pending:
-            return
-        t0 = self._t0
-        if t1 <= t0:
-            t1 = t0 + 1.0  # degenerate window (drained-engine tail flush)
-        n_synth = sum(1 for p in self._pending if p[2] is None)
-        w = (t1 - t0) / max(n_synth, 1)
-        cursor = t0
-        placed: Dict[Tuple[int, int], List[Tuple[float, float]]] = {}
-        for stream, name, span, args, gid in self._pending:
-            if span is None:
-                span = (cursor, cursor + w)
-                cursor += w
-            start, end = float(span[0]), float(span[1])
-            end = max(end, start)  # zero-width passes still render
-            tid = _TID_FOR_STREAM[stream]
-            self._spans.append((name, tid, start, end, args))
-            if gid >= 0:
-                placed.setdefault((gid, tid), []).append((start, end))
-        self._pending.clear()
-        # fused groups: one parent slice nesting the group's sub-passes
-        # (the "one launch with nested sub-pass slices" rendering).  The
-        # parent lives on the main row and covers only main-row members —
-        # verify sub-passes keep their stream-truthful verify-row slices
-        # (they may drain past the iteration, and a cross-row envelope
-        # would partially overlap the next iteration's main work).  A
-        # verify-only fused launch parents on the verify row instead.
-        for gid, (gname, gargs) in sorted(self._groups.items()):
-            members = placed.get((gid, TID_MAIN))
-            tid = TID_MAIN
-            if not members:
-                members = placed.get((gid, TID_VERIFY))
-                tid = TID_VERIFY
-            if not members:
-                continue
-            start = min(s for s, _ in members)
-            end = max(e for _, e in members)
-            self._spans.append((gname, tid, start, end, gargs))
-        self._groups.clear()
-
-    # -- recording ------------------------------------------------------
-
-    def pass_span(self, stream: str, name: str,
-                  span: Optional[Tuple[float, float]],
-                  args: Optional[Dict[str, Any]] = None) -> None:
-        """One device pass on ``stream`` ("main"/"verify").  ``span`` is
-        the runtime's ``(start, finish)`` stream time, or None under the
-        logical clock (laid out at ``end_iteration``)."""
-        a = dict(args or {})
-        a.setdefault("iter", self._it)
-        gid = self._open_group if self._open_group is not None else -1
-        self._pending.append((stream, name, span, a, gid))
-
-    def instant(self, name: str, t: float, stream: str = "protocol",
-                **args: Any) -> None:
-        args.setdefault("iter", self._it)
-        self._instants.append((name, _TID_FOR_STREAM[stream], float(t), args))
-
-    def request_begin(self, rid: int, t: float) -> None:
-        self._asyncs.append(("b", rid, float(t)))
-
-    def request_end(self, rid: int, t: float) -> None:
-        self._asyncs.append(("e", rid, float(t)))
-
-    def begin_group(self, name: str, **args: Any) -> None:
-        """Open a fused-launch group: subsequent ``pass_span`` calls nest
-        under one parent slice until ``end_group``."""
-        args.setdefault("iter", self._it)
-        self._group_id += 1
-        self._groups[self._group_id] = (name, args)
-        self._open_group = self._group_id
-
-    def end_group(self) -> None:
-        self._open_group = None
-
-    # -- export ---------------------------------------------------------
+    def request_end(self, rid: int, t_ns: int) -> None:
+        self._asyncs.append(("e", rid, t_ns))
 
     def to_chrome_trace(
         self, pid: int = 0, process_name: str = "llm42-engine"
@@ -219,12 +163,10 @@ class Tracer(NullTracer):
         """Chrome trace-event JSON (``traceEvents`` array form).
 
         ``pid``/``process_name`` namespace this tracer's rows: the cluster
-        front end exports each replica under its own pid, so Perfetto
-        shows the fleet side by side as separate processes
-        (``Cluster.chrome_trace`` merges the per-replica arrays; the
-        validator keys rows on (pid, tid), so a merged trace validates).
+        front end exports each replica under its own pid
+        (``Cluster.chrome_trace`` merges them; the validator keys rows on
+        (pid, tid), so a merged trace validates).
         """
-        self._flush(self._t0 + 1.0)  # leftovers from a final partial iter
         events: List[Dict[str, Any]] = [
             {"ph": "M", "pid": pid, "tid": 0, "name": "process_name",
              "args": {"name": process_name}},
@@ -232,31 +174,25 @@ class Tracer(NullTracer):
         for tid, tname in _THREAD_NAMES.items():
             events.append({"ph": "M", "pid": pid, "tid": tid,
                            "name": "thread_name", "args": {"name": tname}})
-        # complete slices, per-row (ts, -dur) order => parents precede
-        # children at equal boundaries, rows are monotone
-        for name, tid, start, end, args in sorted(
-            self._spans, key=lambda s: (s[1], s[2], -(s[3] - s[2]))
+        # (start, -end) order: a parent precedes the children it starts with
+        for name, start, end, args in sorted(
+            self.slices, key=lambda s: (_us(s[1]), -_us(s[2]))
         ):
-            # dur from the ROUNDED endpoints: adjacent slices then abut
-            # exactly instead of drifting apart by float error
-            ts = round(start * _US, 3)
+            ts = _us(start)
             events.append({
-                "ph": "X", "pid": pid, "tid": tid, "name": name,
-                "cat": "pass", "ts": ts,
-                "dur": round(round(end * _US, 3) - ts, 3),
-                "args": args,
+                "ph": "X", "pid": pid, "tid": TID_HOST, "name": name,
+                "cat": "host", "ts": ts, "dur": _us(end) - ts, "args": args,
             })
-        for name, tid, t, args in sorted(self._instants, key=lambda i: i[2]):
+        for name, t, args in sorted(self._instants, key=lambda i: i[1]):
             events.append({
-                "ph": "i", "pid": pid, "tid": tid, "name": name,
-                "cat": "protocol", "s": "t", "ts": round(t * _US, 3),
-                "args": args,
+                "ph": "i", "pid": pid, "tid": TID_PROTOCOL, "name": name,
+                "cat": "protocol", "s": "t", "ts": _us(t), "args": args,
             })
         for ph, rid, t in sorted(self._asyncs, key=lambda a: (a[2], a[0])):
             events.append({
                 "ph": ph, "pid": pid, "tid": TID_PROTOCOL,
                 "name": f"request {rid}", "cat": "request", "id": str(rid),
-                "ts": round(t * _US, 3),
+                "ts": _us(t),
             })
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 

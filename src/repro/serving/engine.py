@@ -57,6 +57,7 @@ throughput numbers.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Set
 
@@ -76,12 +77,27 @@ from repro.core.verifier import make_verify_body, make_verify_fn
 from repro.models.base import ModelConfig
 from repro.models.layers import PagedView
 from repro.models.transformer import build_cross_cache, forward
-from repro.obs import Observability, TokenProvenance
+from repro.obs import Observability, TokenProvenance, now_ns
 from repro.serving import costmodel, kv_cache, prefixcache, statepool, streams
 from repro.serving import blockpool
 from repro.serving import scheduler as sched
 from repro.serving.request import Request, State
 from repro.serving.sampler import sample_batch, sample_token, top2_margin
+
+
+def _spanned(name: str) -> Callable:
+    """Run the decorated method inside the host span ``name``
+    (``Observability.span``)."""
+
+    def wrap(method: Callable) -> Callable:
+        @functools.wraps(method)
+        def run(self, *args, **kwargs):
+            with self.obs.span(name):
+                return method(self, *args, **kwargs)
+
+        return run
+
+    return wrap
 
 
 def _bucket(n: int) -> int:
@@ -115,7 +131,7 @@ class Engine:
         prefix_cache: bool = True,  # share committed-prefix KV blocks
         mem_policy: Optional[sched.BlockMemoryPolicy] = None,
         paged_attention: bool = True,  # in-place paged forward + fused step
-        trace: bool = False,  # dual-stream request tracing (repro.obs.trace)
+        trace: bool = False,  # host-span and request tracing (repro.obs.trace)
         audit: bool = False,  # per-token determinism audit (repro.obs.audit)
         tp: int = 1,  # fast-path tensor-parallel width (logical model axis)
     ):
@@ -326,22 +342,6 @@ class Engine:
         m.gauge_fn("verify.inflight",
                    lambda: sum(len(r.pipeline) for r in self.running),
                    unit="windows", help="verify windows currently in flight")
-        # dual-clock stream telemetry (serving.streams)
-        m.gauge_fn("streams.main_busy", lambda: self.runtime.main.busy,
-                   unit="s", help="main-stream busy time (costed clock)")
-        m.gauge_fn("streams.verify_busy", lambda: self.runtime.verify.busy,
-                   unit="s", help="verify-stream busy time (costed clock)")
-        m.gauge_fn("streams.makespan", lambda: self.runtime.makespan,
-                   unit="s", help="completion time of all scheduled work")
-        m.gauge_fn("streams.verify_backlog",
-                   lambda: self.runtime.verify_backlog,
-                   unit="s", help="verify-stream work scheduled past now")
-        m.gauge_fn("streams.outstanding_verdicts",
-                   lambda: self.runtime.outstanding_verdicts,
-                   unit="verdicts", help="verdicts launched but not yet due")
-        m.gauge_fn("streams.peak_outstanding",
-                   lambda: self.runtime.peak_outstanding,
-                   unit="verdicts", help="deepest verdict queue seen")
         # memory subsystem: block pool, preemption lane, prefix cache
         m.gauge_fn("mem.preemptions", lambda: self.num_preemptions,
                    unit="preemptions", help="requests evicted under pressure")
@@ -403,42 +403,27 @@ class Engine:
                        help="adaptive promotions back to overlap")
         self._h_ttft = m.histogram(
             "latency.ttft", unit="s",
-            help="submit to first committed token (stream clock)")
+            help="submit to first committed token (obs clock)")
         self._h_tpot = m.histogram(
             "latency.tpot", unit="s",
-            help="mean inter-token time past T0 (stream clock)")
+            help="mean inter-token time past T0 (obs clock)")
         self._h_e2e = m.histogram(
             "latency.e2e", unit="s",
-            help="submit to retirement (stream clock)")
+            help="submit to retirement (obs clock)")
+        self._h_queue = m.histogram(
+            "latency.queue_wait", unit="s",
+            help="submit to first admission (obs clock)")
 
-    def _charge_main(self, ev: Dict[str, Any]) -> None:
-        """Charge one main-stream pass AND record its trace slice (the
-        runtime stashes the launch's (start, finish) in
-        ``last_main_span``; None under the logical clock — the tracer
-        lays those out across the iteration window)."""
-        self.runtime.charge(ev)
-        tr = self.obs.tracer
-        if tr.enabled:
-            tr.pass_span("main", ev["kind"], self.runtime.last_main_span,
-                         self._trace_args(ev))
-
-    @staticmethod
-    def _trace_args(ev: Dict[str, Any]) -> Dict[str, Any]:
-        """Scalar-only view of an engine event for trace-slice args."""
-        return {
-            k: (list(v) if k == "rids" else v)
-            for k, v in ev.items()
-            if k not in ("wall",)
-            and isinstance(v, (int, float, str, bool, list, tuple))
-        }
+    def _mark_first_token(self, req: Request) -> None:
+        if req.first_token_ns < 0:
+            req.first_token_ns = now_ns()
 
     def _note_t0(self, req: Request, margin: Optional[float] = None) -> None:
         """Metrics + audit for the T0 token a prefill pass just committed
         (sampled under the fixed verify-grade schedule in every mode —
         deterministic by construction, window -1)."""
         self._c_committed.inc()
-        if req.first_token_clock < 0:
-            req.first_token_clock = self.runtime.now
+        self._mark_first_token(req)
         au = self.obs.audit
         if au.enabled:
             schedule = (
@@ -467,12 +452,12 @@ class Engine:
         if tr.enabled:
             tr.instant(
                 "rollback" if outcome.rolled_back else "commit",
-                t=self.runtime.now, rid=req.rid, window=fl.seq,
+                rid=req.rid, window=fl.seq,
                 n_match=fl.n_match, committed=outcome.committed_count,
                 rejected=outcome.rejected, cascaded=len(outcome.cascaded),
             )
-        if req.first_token_clock < 0 and req.committed:
-            req.first_token_clock = self.runtime.now
+        if req.committed:
+            self._mark_first_token(req)
         au = self.obs.audit
         if not au.enabled:
             return
@@ -516,8 +501,9 @@ class Engine:
         cfg, lay = self.cfg, self.pool.layout
         paged, pview = self._paged_fwd, self._pview()
 
-        def step(params, pool, slots, tables, tokens, pos, seeds, temps,
-                 out_pos, top_ks):
+        @jax.named_scope("decode")
+        def decode_step(params, pool, slots, tables, tokens, pos, seeds,
+                        temps, out_pos, top_ks):
             if paged:
                 cache = kv_cache.gather_mixed(pool, lay, slots)
             else:
@@ -539,7 +525,7 @@ class Engine:
                 pool2 = kv_cache.scatter(pool, lay, slots, tables, new_cache)
             return pool2, nxt, margins
 
-        return step
+        return decode_step
 
     def _decode_fn(self, B: int, schedule: Schedule) -> Callable:
         key = ("decode", B, schedule)
@@ -560,8 +546,9 @@ class Engine:
             )
 
             @jax.jit
-            def step(params, pool, slot, table, tokens, plen, seed, temp,
-                     top_k, prefix_embeds):
+            @jax.named_scope("prefill")
+            def prefill_step(params, pool, slot, table, tokens, plen, seed,
+                             temp, top_k, prefix_embeds):
                 slots = slot[None]
                 cache = kv_cache.gather(pool, lay, slots, table[None])
                 if n_prefix:
@@ -590,7 +577,7 @@ class Engine:
                 pool2 = kv_cache.scatter(pool, lay, slots, table[None], new_cache)
                 return pool2, tok, marg
 
-            self._fns[key] = step
+            self._fns[key] = prefill_step
         return self._fns[key]
 
     def _prefill_chunk_body(self, C: int) -> Callable:
@@ -613,7 +600,9 @@ class Engine:
             else VERIFY_SCHEDULE
         )
 
-        def chunk(params, pool, slot, table, embeds, start, last):
+        @jax.named_scope("prefill_chunk")
+        def prefill_chunk_step(params, pool, slot, table, embeds, start,
+                               last):
             slots = slot[None]
             tables = table[None]
             if paged:
@@ -637,7 +626,7 @@ class Engine:
                 pool2 = kv_cache.scatter(pool, lay, slots, tables, new_cache)
             return pool2, logits
 
-        return chunk
+        return prefill_chunk_step
 
     # det: commit-path
     def _prefill_chunk_fn(self, C: int) -> Callable:
@@ -691,7 +680,7 @@ class Engine:
             )
             rec = self.has_recurrent_state
 
-            def fused(params, pool, anchor, pargs, dargs, vargs_list):
+            def fused_step(params, pool, anchor, pargs, dargs, vargs_list):
                 logits_p = nxt = dmarg = None
                 if pbody is not None:
                     pool, logits_p = pbody(params, pool, *pargs)
@@ -710,7 +699,7 @@ class Engine:
                         vouts.append((None, n_match, commit_tok, marg))
                 return pool, anchor, logits_p, nxt, dmarg, vouts
 
-            self._fns[key] = jax.jit(fused)
+            self._fns[key] = jax.jit(fused_step)
         return self._fns[key]
 
     # ------------------------------------------------------------------
@@ -720,13 +709,13 @@ class Engine:
     def submit(self, req: Request) -> None:
         self._check_capacity(req)
         req.state = State.QUEUED
-        req.submit_clock = self.runtime.now
+        req.submit_ns = now_ns()
         self.queue.append(req)
         self._c_submitted.inc()
         tr = self.obs.tracer
         if tr.enabled:
-            tr.request_begin(req.rid, req.submit_clock)
-            tr.instant("submit", t=req.submit_clock, rid=req.rid,
+            tr.request_begin(req.rid, req.submit_ns)
+            tr.instant("submit", rid=req.rid,
                        prompt_len=req.prompt_len,
                        deterministic=req.sampling.is_deterministic)
 
@@ -820,7 +809,8 @@ class Engine:
             evicted = self.prefix_cache.evict_lru(alloc)
             if evicted is None:
                 break
-            self.pool.free_blocks([evicted])
+            with self.obs.span("blockpool.wipe"):
+                self.pool.free_blocks([evicted])
             bid = alloc.alloc()
         return bid
 
@@ -869,7 +859,9 @@ class Engine:
         for bid in req.blocks:
             if alloc.decref(bid) == 0 and bid not in alloc.cached:
                 freed.append(bid)
-        self.pool.free_blocks(freed)
+        if freed:
+            with self.obs.span("blockpool.wipe"):
+                self.pool.free_blocks(freed)
         req.blocks = []
         req.blocks_shared = 0
 
@@ -957,6 +949,7 @@ class Engine:
         if self.prefix_cache is not None:
             self.prefix_cache.note_lookup(len(matched))
         req.slot = self.pool.alloc()
+        self._h_queue.observe((now_ns() - req.submit_ns) / 1e9)
         cached = req.cached_prefix_tokens
         if cached:
             self.events.append({
@@ -965,7 +958,7 @@ class Engine:
             })
         tr = self.obs.tracer
         if tr.enabled:
-            tr.instant("admit", t=self.runtime.now, rid=req.rid,
+            tr.instant("admit", rid=req.rid,
                        cached_tokens=cached, slot=req.slot)
         if self._use_chunked(req) or cached > 0:
             # third lane: prefill advances chunk-by-chunk via scheduler
@@ -977,9 +970,11 @@ class Engine:
             if not self._use_chunked(req):
                 # cache-hit tail under the exclusive-prefill engine: run
                 # the tail synchronously (legacy admission semantics)
-                self._prefill_tail_sync(req)
+                with self.obs.span("engine.prefill"):
+                    self._prefill_tail_sync(req)
         else:
-            self._prefill(req)
+            with self.obs.span("engine.prefill"):
+                self._prefill(req)
             req.state = State.RUNNING
         self.running.append(req)
         return True
@@ -997,6 +992,7 @@ class Engine:
             self.statepool.note_splice(req.slot, len(outcome.cascaded))
         self.statepool.note_preempt(req.slot)
 
+    @_spanned("engine.preempt")
     def preempt(self, req: Request) -> bool:
         """Evict a running request's KV blocks (the memory policy's lane,
         and a test hook for adversarial eviction schedules).  The request
@@ -1031,10 +1027,11 @@ class Engine:
         })
         tr = self.obs.tracer
         if tr.enabled:
-            tr.instant("preempt", t=self.runtime.now, rid=req.rid,
+            tr.instant("preempt", rid=req.rid,
                        dropped_tokens=dropped, committed=len(req.committed))
         return True
 
+    @_spanned("engine.restore")
     def _restore(self, req: Request) -> None:
         """Re-admit a preempted request by deterministic recompute: replay
         its committed stream (prompt + committed[:-1] — the last committed
@@ -1081,7 +1078,7 @@ class Engine:
         tr = self.obs.tracer
         if tr.enabled:
             tr.instant(
-                "restore", t=self.runtime.now, rid=req.rid,
+                "restore", rid=req.rid,
                 replay_tokens=max(req.prefill_total - req.prefill_pos, 0),
                 rematched_blocks=len(matched),
             )
@@ -1117,7 +1114,7 @@ class Engine:
             "wall": wall, "iter": self._now, "cached": start,
             "replay": replay,
         }
-        self._charge_main(ev)
+        self.runtime.charge(ev)
         self.events.append(ev)
 
     def mem_stats(self) -> Dict[str, Any]:
@@ -1281,16 +1278,28 @@ class Engine:
             "replay": replay,
         }
 
-    def _prefill_advance(self, req: Request, C: int) -> Dict[str, Any]:
+    def _prefill_advance(self, req: Request, C: int,
+                         prepared=None) -> Dict[str, Any]:
         """Advance one fixed-shape C-token prefill chunk as a standalone
-        launch (the fused step composes the same prep/body/post instead)."""
-        args, s, real = self._prefill_chunk_prep(req, C)
+        launch (the fused step composes the same prep/body/post instead).
+        ``prepared`` is ``_prefill_chunk_prep``'s result where the caller
+        built it already."""
+        args, s, real = prepared or self._prefill_chunk_prep(req, C)
         t0 = time.perf_counter()
-        self.pool.data, logits = self._prefill_chunk_fn(C)(
-            self.params, self.pool.data, *args
-        )
+        with self.obs.span("engine.launch"):
+            self.pool.data, logits = self._prefill_chunk_fn(C)(
+                self.params, self.pool.data, *args
+            )
+        if s + real >= req.prefill_total and not req.replaying:
+            self._wait(logits)  # post samples T0 from them
         wall = time.perf_counter() - t0
         return self._prefill_chunk_post(req, C, s, real, logits, wall)
+
+    def _wait(self, *outs) -> None:
+        """Block until a launch's ``outs`` are ready, where the host's
+        first read of them would block anyway (``engine.wait``)."""
+        with self.obs.span("engine.wait"):
+            jax.block_until_ready(outs)
 
     # det: commit-path
     def _prefill(self, req: Request) -> None:
@@ -1314,12 +1323,15 @@ class Engine:
             )
         table = self.pool.table_array([req.blocks])[0]
         t0 = time.perf_counter()
-        self.pool.data, tok, marg = self._prefill_fn(P)(
-            self.params, self.pool.data, jnp.int32(req.slot), table, tokens,
-            jnp.int32(req.prompt_len), jnp.int32(req.sampling.seed),
-            jnp.float32(req.sampling.temperature),
-            jnp.int32(req.sampling.top_k), prefix,
-        )
+        with self.obs.span("engine.launch"):
+            self.pool.data, tok, marg = self._prefill_fn(P)(
+                self.params, self.pool.data, jnp.int32(req.slot), table,
+                tokens, jnp.int32(req.prompt_len),
+                jnp.int32(req.sampling.seed),
+                jnp.float32(req.sampling.temperature),
+                jnp.int32(req.sampling.top_k), prefix,
+            )
+        self._wait(tok, marg)
         wall = time.perf_counter() - t0
         req.last_sched = self._now
         # commit point == post-prompt state: first verify replay anchor
@@ -1332,7 +1344,7 @@ class Engine:
             "kind": "prefill", "tokens": req.prompt_len + (cfg.num_prefix_embeds or 0),
             "padded": P + (cfg.num_prefix_embeds or 0), "wall": wall, "iter": self._now,
         }
-        self._charge_main(ev)
+        self.runtime.charge(ev)
         self.events.append(ev)
 
     def _prefill_sliding(self, req: Request) -> None:
@@ -1350,7 +1362,7 @@ class Engine:
             "padded": ((req.prompt_len + W - 1) // W) * W, "wall": wall,
             "iter": self._now,
         }
-        self._charge_main(ev)
+        self.runtime.charge(ev)
         self.events.append(ev)
 
     def _view(self, stalled: Optional[Set[int]] = None) -> sched.SchedulerView:
@@ -1445,8 +1457,7 @@ class Engine:
             else:
                 r.committed.append(t)
                 self._c_committed.inc()
-                if r.first_token_clock < 0:
-                    r.first_token_clock = self.runtime.now
+                self._mark_first_token(r)
                 if au.enabled:
                     au.record(TokenProvenance(
                         rid=r.rid, index=len(r.committed) - 1, token=t,
@@ -1461,15 +1472,25 @@ class Engine:
         }
 
     def _decode_step(self, batch: List[Request]) -> Dict[str, Any]:
-        B = len(batch)
-        schedule = self._decode_schedule(B)
-        args, pos = self._decode_prep(batch)
+        schedule = self._decode_schedule(len(batch))
+        with self.obs.span("engine.prep"):
+            args, pos = self._decode_prep(batch)
+        return self._decode_launch(batch, schedule, args, pos)
+
+    def _decode_launch(self, batch: List[Request], schedule: Schedule,
+                       args, pos: List[int]) -> Dict[str, Any]:
+        """One standalone decode pass on prepared ``args``: launch, wait
+        for its tokens, land them."""
         t0 = time.perf_counter()
-        self.pool.data, nxt, margins = self._decode_fn(B, schedule)(
-            self.params, self.pool.data, *args
-        )
+        with self.obs.span("engine.launch"):
+            self.pool.data, nxt, margins = self._decode_fn(
+                len(batch), schedule
+            )(self.params, self.pool.data, *args)
+        self._wait(nxt, margins)
         wall = time.perf_counter() - t0
-        return self._decode_post(batch, schedule, pos, nxt, wall, margins)
+        with self.obs.span("engine.post"):
+            return self._decode_post(batch, schedule, pos, nxt, wall,
+                                     margins)
 
     def _pad_verify_row(self, inputs, cands, cand_lens, starts, bases, slots,
                         seeds, temps, tks, ring_idxs, table_rows) -> None:
@@ -1489,6 +1510,7 @@ class Engine:
         ring_idxs.append(0)
         table_rows.append([])
 
+    @_spanned("engine.verify_prep")
     def _verify_prelaunch(self, rows: List[Request]):
         """Host protocol work for one deferred verify group, BEFORE the
         device pass: build each row's replay inputs, then move its window
@@ -1567,6 +1589,7 @@ class Engine:
             "deferred": deferred,
         }
 
+    @_spanned("engine.verify_post")
     def _verify_postlaunch(
         self, rows: List[Request], fls, ev: Dict[str, Any], ring_idxs,
         slots, starts, n_match, commit_tok, commit_rows, margins=None,
@@ -1582,9 +1605,6 @@ class Engine:
         ready_at = self.runtime.launch_verify(ev, sync=False)
         submitted_at = self.runtime.now
         tr = self.obs.tracer
-        if tr.enabled:
-            tr.pass_span("verify", "verify", self.runtime.last_verify_span,
-                         self._trace_args(ev))
         self._c_windows.inc(len(rows))
         audit = self.obs.audit.enabled
         if commit_rows is not None:
@@ -1600,7 +1620,7 @@ class Engine:
                 # (front normalization pops both in lockstep)
                 fl.margins = [float(x) for x in margins[i]]
             if tr.enabled:
-                tr.instant("verify_submit", t=submitted_at, rid=r.rid,
+                tr.instant("verify_submit", rid=r.rid,
                            window=fl.seq, cands=len(fl.cands),
                            ready_at=ready_at)
             self.statepool.note_submit(r.slot, starts[i] + W)
@@ -1657,27 +1677,14 @@ class Engine:
         """
         G, W = self.group, self.window
         rows = group[:G]
+        span = self.obs.span
         if defer:
-            args, fls, ring_idxs, slots, starts, n_pad = (
-                self._verify_prelaunch(rows)
-            )
-            t0 = time.perf_counter()
-            if self.has_recurrent_state:
-                (self.pool.data, self.statepool.anchor, commit_rows, n_match,
-                 commit_tok, _v, margins) = self._verify_fn(
-                    self.params, self.pool.data, self.statepool.anchor, *args
+            with span("engine.prep"):
+                args, fls, ring_idxs, slots, starts, n_pad = (
+                    self._verify_prelaunch(rows)
                 )
-            else:
-                commit_rows = None
-                self.pool.data, n_match, commit_tok, _v, margins = (
-                    self._verify_fn(self.params, self.pool.data, *args)
-                )
-            wall = time.perf_counter() - t0
-            ev = self._verify_event(rows, starts, n_pad, wall, n_decodable,
-                                    True)
-            self._verify_postlaunch(rows, fls, ev, ring_idxs, slots, starts,
-                                    n_match, commit_tok, commit_rows, margins)
-            return ev
+            return self._verify_launch(rows, fls, ring_idxs, slots, starts,
+                                       n_pad, args, n_decodable)
         # ---- sync path: FIFOs are empty, the verdict applies on the spot
         assert len({id(r) for r in rows}) == len(rows), (
             "a request may contribute one window per grouped pass — chained "
@@ -1689,54 +1696,90 @@ class Engine:
         )
         ring_idxs: List[int] = []
         table_rows: List[List[int]] = []
-        for r in rows:
-            i, c, cl, sp, ob = dvr.build_verify_row(r, W)
-            inputs.append(i)
-            cands.append(c)
-            cand_lens.append(cl)
-            starts.append(sp)
-            bases.append(ob)
-            slots.append(r.slot)
-            seeds.append(r.sampling.seed)
-            temps.append(r.sampling.temperature)
-            tks.append(r.sampling.top_k)
-            table_rows.append(r.blocks)
-            r.last_sched = self._now
-            ring_idxs.append(0)  # sync: FIFO empty, ring 0 is free
-        for _ in range(n_pad):
-            self._pad_verify_row(inputs, cands, cand_lens, starts, bases,
-                                 slots, seeds, temps, tks, ring_idxs,
-                                 table_rows)
-        t0 = time.perf_counter()
-        args = (
-            jnp.array(slots, jnp.int32),
-            self.pool.table_array(table_rows),
-            jnp.array(starts, jnp.int32),
-            jnp.array(inputs, jnp.int32), jnp.array(cands, jnp.int32),
-            jnp.array(cand_lens, jnp.int32), jnp.array(seeds, jnp.int32),
-            jnp.array(temps, jnp.float32), jnp.array(bases, jnp.int32),
-            jnp.array(tks, jnp.int32),
-        )
-        if self.has_recurrent_state:
-            (self.pool.data, self.statepool.anchor, commit_rows, n_match,
-             commit_tok, _v, margins) = self._verify_fn(
-                self.params, self.pool.data, self.statepool.anchor, *args
+        with span("engine.prep"):
+            for r in rows:
+                i, c, cl, sp, ob = dvr.build_verify_row(r, W)
+                inputs.append(i)
+                cands.append(c)
+                cand_lens.append(cl)
+                starts.append(sp)
+                bases.append(ob)
+                slots.append(r.slot)
+                seeds.append(r.sampling.seed)
+                temps.append(r.sampling.temperature)
+                tks.append(r.sampling.top_k)
+                table_rows.append(r.blocks)
+                r.last_sched = self._now
+                ring_idxs.append(0)  # sync: FIFO empty, ring 0 is free
+            for _ in range(n_pad):
+                self._pad_verify_row(inputs, cands, cand_lens, starts, bases,
+                                     slots, seeds, temps, tks, ring_idxs,
+                                     table_rows)
+            t0 = time.perf_counter()
+            args = (
+                jnp.array(slots, jnp.int32),
+                self.pool.table_array(table_rows),
+                jnp.array(starts, jnp.int32),
+                jnp.array(inputs, jnp.int32), jnp.array(cands, jnp.int32),
+                jnp.array(cand_lens, jnp.int32), jnp.array(seeds, jnp.int32),
+                jnp.array(temps, jnp.float32), jnp.array(bases, jnp.int32),
+                jnp.array(tks, jnp.int32),
             )
-            self.statepool.checkpoint(ring_idxs, slots, commit_rows)
-        else:
-            self.pool.data, n_match, commit_tok, _v, margins = (
-                self._verify_fn(self.params, self.pool.data, *args)
-            )
+        commit_rows, n_match, commit_tok, margins = self._run_verify(args)
         wall = time.perf_counter() - t0
+        with span("engine.post"):
+            return self._verify_sync_post(rows, ring_idxs, slots, starts,
+                                          n_pad, n_match, commit_tok,
+                                          commit_rows, margins, wall,
+                                          n_decodable)
+
+    def _verify_launch(self, rows: List[Request], fls, ring_idxs, slots,
+                       starts, n_pad: int, args,
+                       n_decodable: int) -> Dict[str, Any]:
+        """One standalone deferred verify pass on prepared ``args``:
+        launch, wait for its verdicts, land them in the FIFOs."""
+        t0 = time.perf_counter()
+        commit_rows, n_match, commit_tok, margins = self._run_verify(args)
+        wall = time.perf_counter() - t0
+        with self.obs.span("engine.post"):
+            ev = self._verify_event(rows, starts, n_pad, wall, n_decodable,
+                                    True)
+            self._verify_postlaunch(rows, fls, ev, ring_idxs, slots, starts,
+                                    n_match, commit_tok, commit_rows, margins)
+        return ev
+
+    def _run_verify(self, args):
+        """Launch the standalone verify pass on ``args`` and wait for its
+        verdicts: ``(commit_rows, n_match, commit_tok, margins)``, with
+        ``commit_rows`` None on attention-only archs."""
+        with self.obs.span("engine.launch"):
+            if self.has_recurrent_state:
+                (self.pool.data, self.statepool.anchor, commit_rows, n_match,
+                 commit_tok, _v, margins) = self._verify_fn(
+                    self.params, self.pool.data, self.statepool.anchor, *args
+                )
+            else:
+                commit_rows = None
+                self.pool.data, n_match, commit_tok, _v, margins = (
+                    self._verify_fn(self.params, self.pool.data, *args)
+                )
+        self._wait(n_match, commit_tok, margins, commit_rows)
+        return commit_rows, n_match, commit_tok, margins
+
+    def _verify_sync_post(self, rows: List[Request], ring_idxs, slots,
+                          starts, n_pad: int, n_match, commit_tok,
+                          commit_rows, margins, wall: float,
+                          n_decodable: int) -> Dict[str, Any]:
+        """Apply a sync verify pass's verdicts on the spot."""
+        W = self.window
+        if commit_rows is not None:
+            self.statepool.checkpoint(ring_idxs, slots, commit_rows)
         n_match = [int(n) for n in n_match]
         commit_tok = [int(t) for t in commit_tok]
         ev = self._verify_event(rows, starts, n_pad, wall, n_decodable,
                                 False)
         self.runtime.launch_verify(ev, sync=True)
         tr, au = self.obs.tracer, self.obs.audit
-        if tr.enabled:
-            tr.pass_span("verify", "verify", self.runtime.last_verify_span,
-                         self._trace_args(ev))
         for i, (r, n, t) in enumerate(zip(rows, n_match, commit_tok)):
             cand_len = len(r.candidates)
             base = len(r.committed)
@@ -1748,12 +1791,11 @@ class Engine:
                 self._c_recomputed.inc(nrej)
                 self._h_rollback_depth.observe(nrej)
             if tr.enabled:
-                tr.instant("rollback" if nrej else "commit",
-                           t=self.runtime.now, rid=r.rid,
+                tr.instant("rollback" if nrej else "commit", rid=r.rid,
                            window=r.num_verify_passes - 1, n_match=n,
                            committed=nc, rejected=nrej, cascaded=0)
-            if r.first_token_clock < 0 and r.committed:
-                r.first_token_clock = self.runtime.now
+            if r.committed:
+                self._mark_first_token(r)
             if au.enabled:
                 # sync windows never enter the in-flight FIFO, so the
                 # audit window id is the request's verify-pass ordinal
@@ -1796,27 +1838,30 @@ class Engine:
         follower is marked ``fused``.  Returns ``(pev, dev, vev, vextra)``
         — the composite-event parts (``vextra`` = verify groups past the
         first)."""
+        span = self.obs.span
         C = self._chunk_size()
         preq = plan.prefill
-        pargs = ps = preal = None
-        if preq is not None:
-            pargs, ps, preal = self._prefill_chunk_prep(preq, C)
-        batch = [r for r in plan.decode if not r.done_decoding()]
-        B = len(batch)
-        schedule = self._decode_schedule(B)
-        dargs = dpos = None
-        if batch:
-            dargs, dpos = self._decode_prep(batch)
-        groups = (
-            self._pack_verify_groups(list(plan.verify)) if plan.verify else []
-        )
-        vargs_list, vstates = [], []
-        for rows in groups:
-            args, fls, ring_idxs, slots, starts, n_pad = (
-                self._verify_prelaunch(rows)
+        with span("engine.prep"):
+            pargs = ps = preal = None
+            if preq is not None:
+                pargs, ps, preal = self._prefill_chunk_prep(preq, C)
+            batch = [r for r in plan.decode if not r.done_decoding()]
+            B = len(batch)
+            schedule = self._decode_schedule(B)
+            dargs = dpos = None
+            if batch:
+                dargs, dpos = self._decode_prep(batch)
+            groups = (
+                self._pack_verify_groups(list(plan.verify))
+                if plan.verify else []
             )
-            vargs_list.append(args)
-            vstates.append((rows, fls, ring_idxs, slots, starts, n_pad))
+            vargs_list, vstates = [], []
+            for rows in groups:
+                args, fls, ring_idxs, slots, starts, n_pad = (
+                    self._verify_prelaunch(rows)
+                )
+                vargs_list.append(args)
+                vstates.append((rows, fls, ring_idxs, slots, starts, n_pad))
         n_subs = (preq is not None) + (1 if batch else 0) + len(groups)
         if n_subs == 0:
             return None, None, None, []
@@ -1826,101 +1871,73 @@ class Engine:
             # single lane: dispatch to the standalone per-lane jit (same
             # bodies, no extra compile variants for degenerate shapes)
             if preq is not None:
-                t0 = time.perf_counter()
-                self.pool.data, logits = self._prefill_chunk_fn(C)(
-                    self.params, self.pool.data, *pargs
-                )
-                pev = self._prefill_chunk_post(
-                    preq, C, ps, preal, logits, time.perf_counter() - t0
-                )
-                self._charge_main(pev)
+                pev = self._prefill_advance(preq, C, (pargs, ps, preal))
+                self.runtime.charge(pev)
                 return pev, None, None, []
             if batch:
-                t0 = time.perf_counter()
-                self.pool.data, nxt, dmarg = self._decode_fn(B, schedule)(
-                    self.params, self.pool.data, *dargs
-                )
-                dev = self._decode_post(
-                    batch, schedule, dpos, nxt, time.perf_counter() - t0,
-                    dmarg,
-                )
-                self._charge_main(dev)
+                dev = self._decode_launch(batch, schedule, dargs, dpos)
+                self.runtime.charge(dev)
                 return None, dev, None, []
             rows, fls, ring_idxs, slots, starts, n_pad = vstates[0]
-            t0 = time.perf_counter()
-            if rec:
-                (self.pool.data, self.statepool.anchor, commit_rows, n_match,
-                 commit_tok, _v, vmarg) = self._verify_fn(
-                    self.params, self.pool.data, self.statepool.anchor,
-                    *vargs_list[0]
-                )
-            else:
-                commit_rows = None
-                self.pool.data, n_match, commit_tok, _v, vmarg = (
-                    self._verify_fn(self.params, self.pool.data,
-                                    *vargs_list[0])
-                )
-            vev = self._verify_event(
-                rows, starts, n_pad, time.perf_counter() - t0, n_decodable,
-                True,
+            vev = self._verify_launch(
+                rows, fls, ring_idxs, slots, starts, n_pad, vargs_list[0],
+                n_decodable,
             )
-            self._verify_postlaunch(rows, fls, vev, ring_idxs, slots, starts,
-                                    n_match, commit_tok, commit_rows, vmarg)
             return None, None, vev, []
 
         t0 = time.perf_counter()
         anchor = self.statepool.anchor if rec else None
-        pool, anchor, logits_p, nxt, dmarg, vouts = self._fused_fn(
-            C if preq is not None else None, B, schedule, len(groups)
-        )(
-            self.params, self.pool.data, anchor,
-            pargs if pargs is not None else (),
-            dargs if dargs is not None else (),
-            tuple(vargs_list),
-        )
+        with span("engine.launch"):
+            pool, anchor, logits_p, nxt, dmarg, vouts = self._fused_fn(
+                C if preq is not None else None, B, schedule, len(groups)
+            )(
+                self.params, self.pool.data, anchor,
+                pargs if pargs is not None else (),
+                dargs if dargs is not None else (),
+                tuple(vargs_list),
+            )
         self.pool.data = pool
         if rec:
             self.statepool.anchor = anchor
+        # a fused launch holds a decode or a verify group, whose outputs
+        # the host reads first thing in post
+        self._wait(logits_p, nxt, dmarg, vouts)
         wall = time.perf_counter() - t0
         share = wall / n_subs
         self._c_fused.inc()
-        tr = self.obs.tracer
-        if tr.enabled:
-            # one launch with nested sub-pass slices: the sub-passes
-            # recorded below nest under a fused_step parent span
-            tr.begin_group("fused_step", iter=self._now, subs=n_subs)
 
         pev = dev = vev = None
         vextra: List[Dict[str, Any]] = []
         lead = True
-        if preq is not None:
-            pev = self._prefill_chunk_post(preq, C, ps, preal, logits_p,
-                                           share)
-            lead = False
-            self._charge_main(pev)
-        if batch:
-            dev = self._decode_post(batch, schedule, dpos, nxt, share, dmarg)
-            if not lead:
-                dev["fused"] = True
-            lead = False
-            self._charge_main(dev)
-        for gi, (rows, fls, ring_idxs, slots, starts, n_pad) in enumerate(
-            vstates
-        ):
-            commit_rows, n_match, commit_tok, vmarg = vouts[gi]
-            ev = self._verify_event(rows, starts, n_pad, share, n_decodable,
-                                    True)
-            if not lead:
-                ev["fused"] = True
-            lead = False
-            self._verify_postlaunch(rows, fls, ev, ring_idxs, slots, starts,
-                                    n_match, commit_tok, commit_rows, vmarg)
-            if vev is None:
-                vev = ev
-            else:
-                vextra.append(ev)
-        if tr.enabled:
-            tr.end_group()
+        with span("engine.post"):
+            if preq is not None:
+                pev = self._prefill_chunk_post(preq, C, ps, preal, logits_p,
+                                               share)
+                lead = False
+                self.runtime.charge(pev)
+            if batch:
+                dev = self._decode_post(batch, schedule, dpos, nxt, share,
+                                        dmarg)
+                if not lead:
+                    dev["fused"] = True
+                lead = False
+                self.runtime.charge(dev)
+            for gi, (rows, fls, ring_idxs, slots, starts, n_pad) in enumerate(
+                vstates
+            ):
+                commit_rows, n_match, commit_tok, vmarg = vouts[gi]
+                ev = self._verify_event(rows, starts, n_pad, share,
+                                        n_decodable, True)
+                if not lead:
+                    ev["fused"] = True
+                lead = False
+                self._verify_postlaunch(rows, fls, ev, ring_idxs, slots,
+                                        starts, n_match, commit_tok,
+                                        commit_rows, vmarg)
+                if vev is None:
+                    vev = ev
+                else:
+                    vextra.append(ev)
         return pev, dev, vev, vextra
 
     def _finish(self, req: Request) -> None:
@@ -1936,20 +1953,21 @@ class Engine:
         req.slot = -1
         self.finished.append(req)
         self._c_finished.inc()
-        now = self.runtime.now
-        if req.submit_clock >= 0:
-            self._h_e2e.observe(now - req.submit_clock)
-            if req.first_token_clock >= 0:
-                self._h_ttft.observe(req.first_token_clock - req.submit_clock)
-        if req.first_token_clock >= 0 and req.num_output > 1:
+        now = now_ns()
+        if req.submit_ns >= 0:
+            self._h_e2e.observe((now - req.submit_ns) / 1e9)
+            if req.first_token_ns >= 0:
+                self._h_ttft.observe(
+                    (req.first_token_ns - req.submit_ns) / 1e9)
+        if req.first_token_ns >= 0 and req.num_output > 1:
             self._h_tpot.observe(
-                (now - req.first_token_clock) / (req.num_output - 1)
+                (now - req.first_token_ns) / 1e9 / (req.num_output - 1)
             )
         if self.mode == Mode.LLM42 and req.sampling.is_deterministic:
             self._h_acceptance.observe(req.accept_ema)
         tr = self.obs.tracer
         if tr.enabled:
-            tr.instant("retire", t=now, rid=req.rid,
+            tr.instant("retire", rid=req.rid,
                        committed=req.num_output,
                        rollbacks=req.num_rollbacks,
                        verify_passes=req.num_verify_passes)
@@ -1971,6 +1989,7 @@ class Engine:
     # main loop
     # ------------------------------------------------------------------
 
+    @_spanned("engine.step")
     def step(self) -> bool:
         """One scheduler iteration.  Returns False when fully drained.
 
@@ -2002,32 +2021,29 @@ class Engine:
         (``streams.DualClockRuntime``), and a sync verify (pause policy, or
         an ``AdaptivePolicy`` demotion) blocks the main stream.  An
         iteration that ran >= 2 passes still emits a single composite
-        ``overlap`` event for log replay (``costmodel``)."""
+        ``overlap`` event for log replay (``costmodel``).
+
+        Each phase runs inside a host span (``engine.*``, ``repro.obs``):
+        on the profiler's clock, and counted in the metrics registry."""
+        span = self.obs.span
         self._now += 1
         self.runtime.begin_iteration()
         self._c_iters.inc()
-        tr = self.obs.tracer
-        if tr.enabled:
-            # iteration window start: under the logical clock
-            # begin_iteration just advanced main by 1.0, so the window the
-            # tick represents is [now - 1, now]; costed passes extend the
-            # frontier from now onward
-            tr.begin_iteration(
-                self._now,
-                self.runtime.now - (1.0 if self.runtime.logical else 0.0),
-            )
-        applied = self._apply_due_verdicts()
-        self._retire()
-        self._admit()
+        with span("engine.verdicts"):
+            applied = self._apply_due_verdicts()
+        with span("engine.retire"):
+            self._retire()
+        with span("engine.admit"):
+            self._admit()
         if not self.running and not self.queue and not self.preempted:
-            if tr.enabled:
-                tr.end_iteration(self.runtime.now)
             return False
         self.peak_running = max(self.peak_running, len(self.running))
 
-        stalled = self._ensure_memory()
-        view = self._view(stalled)
-        plan = self.scheduler.plan(view)
+        with span("engine.memory"):
+            stalled = self._ensure_memory()
+        with span("engine.plan"):
+            view = self._view(stalled)
+            plan = self.scheduler.plan(view)
         defer = self.scheduler.defers_verify and not plan.sync_verify
         pev = dev = vev = None
         vextra: List[Dict[str, Any]] = []
@@ -2042,12 +2058,12 @@ class Engine:
             # launches one window per request per iteration.
             if plan.prefill is not None:
                 pev = self._prefill_advance(plan.prefill, self._chunk_size())
-                self._charge_main(pev)
+                self.runtime.charge(pev)
             if plan.decode:
                 batch = [r for r in plan.decode if not r.done_decoding()]
                 if batch:
                     dev = self._decode_step(batch)
-                    self._charge_main(dev)
+                    self.runtime.charge(dev)
             if plan.verify:
                 rows, seen = [], set()
                 for r in plan.verify:
@@ -2059,8 +2075,6 @@ class Engine:
                     n_decodable=len(sched.decodable(view)),
                 )
         self.runtime.end_iteration()
-        if tr.enabled:
-            tr.end_iteration(self.runtime.now)
 
         subs = [("decode", dev), ("verify", vev), ("prefill", pev)]
         present = [(k, ev) for k, ev in subs if ev is not None]

@@ -131,10 +131,11 @@ class Request:
     num_cascaded_windows: int = 0  # windows discarded by cascade rollbacks
     prefill_time: float = -1.0
     finish_time: float = -1.0
-    # stream-clock latency marks (obs.metrics TTFT/TPOT/e2e histograms):
-    # set at submit / first committed token, read at retirement
-    submit_clock: float = -1.0
-    first_token_clock: float = -1.0
+    # obs-clock latency marks (repro.obs.now_ns) for the latency.*
+    # histograms: set at submit / first committed token, read at admission
+    # and retirement
+    submit_ns: int = -1
+    first_token_ns: int = -1
     # encdec / multimodal payloads (stub-frontend outputs)
     enc_embeds: Optional[object] = None
     prefix_embeds: Optional[object] = None

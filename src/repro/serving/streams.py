@@ -180,13 +180,6 @@ class DualClockRuntime:
         #: verdicts per request can be airborne — this is the occupancy
         #: telemetry benchmarks report alongside verify-stream busy time
         self.peak_outstanding = 0
-        #: (start, finish) of the most recent costed launch on each stream
-        #: — the tracer reads these right after ``charge`` /
-        #: ``launch_verify`` to place the pass's slice on the timeline.
-        #: None under the logical clock (passes there have no extent; the
-        #: tracer synthesizes a layout inside the iteration window instead)
-        self.last_main_span: Optional[Tuple[float, float]] = None
-        self.last_verify_span: Optional[Tuple[float, float]] = None
         self._n_launches = 0
         self._t0 = 0.0
         self._did_main_work = False
@@ -248,10 +241,9 @@ class DualClockRuntime:
         separate kernel launches on one stream."""
         self._did_main_work = True
         if self.logical:
-            self.last_main_span = None
             return 0.0
         dur = self.cost_fn(ev)
-        self.last_main_span = self.main.launch(dur)
+        self.main.launch(dur)
         return dur
 
     def launch_verify(self, ev: Dict[str, Any], *, sync: bool = False) -> float:
@@ -267,7 +259,6 @@ class DualClockRuntime:
         """
         lat = self._latency_for_launch()
         if self.logical:
-            self.last_verify_span = None
             if sync:
                 self._did_main_work = True
                 return self.main.now
@@ -280,13 +271,11 @@ class DualClockRuntime:
             # exclusive: everything waits on the pass (and on any verify
             # work still draining); busy time accrues to the verify stream
             # so occupancy telemetry sees sync and deferred passes alike
-            start, finish = self.verify.launch(dur, not_before=self.main.now)
-            self.last_verify_span = (start, finish)
+            _, finish = self.verify.launch(dur, not_before=self.main.now)
             self.main.wait(finish)
             self._did_main_work = True
             return self.main.now
         start, finish = self.verify.launch(dur, not_before=self._t0)
-        self.last_verify_span = (start, finish)
         overlap = max(0.0, min(self.main.now, finish) - max(self._t0, start))
         self.main.advance(self.contention * overlap)
         ready = finish + lat

@@ -1,13 +1,15 @@
-"""Observability-layer tests (ISSUE 9).
+"""Observability-layer tests.
 
-Three contracts:
+Four contracts:
 
 * **Schema** — engine-exported Chrome/Perfetto traces pass
   :func:`repro.obs.validate_chrome_trace` (and the validator itself
   rejects each class of malformed trace).
+* **One clock** — the engine's host spans land in a JAX profiler trace,
+  nested as called, at the times the tracer recorded for them.
 * **Observer-effect freedom** — committed streams are bitwise identical
-  with tracing+auditing on vs off, across scheduler policies and
-  speculation depths (hypothesis-driven).
+  with spans, tracer, audit and profiler on vs off, across scheduler
+  policies and speculation depths (hypothesis-driven).
 * **Audit coverage** — every committed token of an audited run has
   exactly one provenance record (schedule + window + margin for
   verify-committed tokens); rollback victims have none.
@@ -16,7 +18,11 @@ Plus unit tests for the metrics registry, the ``mem_stats`` compat shim,
 and the ``persist.py --check`` tolerance comparator.
 """
 
+import glob
 import json
+import os
+import shutil
+import tempfile
 
 import jax
 import pytest
@@ -28,11 +34,12 @@ from repro.models import init_params
 from repro.obs import (
     AuditLog,
     MetricsRegistry,
+    Observability,
     TokenProvenance,
-    Tracer,
+    now_ns,
     validate_chrome_trace,
 )
-from repro.obs.trace import TID_MAIN, TID_PROTOCOL, TID_VERIFY
+from repro.obs.trace import TID_HOST, TID_PROTOCOL
 from repro.serving.engine import Engine
 from repro.serving.request import Request, SamplingParams
 from repro.serving.scheduler import (
@@ -72,16 +79,62 @@ def _reqs(cfg, n=4, max_new=14):
     ]
 
 
+#: steps [start, stop) of a profiled run that the JAX profiler records
+PROFILED_STEPS = (3, 6)
+
+
 def _run(cfg, params, *, scheduler="overlap", spec_depth=1, trace=False,
-         audit=False, n=4, max_new=14):
+         audit=False, n=4, max_new=14, profile=False):
+    """One engine run to drain.  ``profile=True`` records the steps
+    ``PROFILED_STEPS`` with the JAX profiler and keeps, on the engine,
+    the obs-clock bounds of the recorded span (``profiled_ns``) and the
+    trace's host events (``profiled_events``, see ``_host_events``)."""
     eng = Engine(cfg, params, mode=Mode.LLM42, policy=DRIFTY, window=5,
                  group=2, max_batch=8, capacity=256,
                  scheduler=SCHEDULERS[scheduler](), spec_depth=spec_depth,
                  trace=trace, audit=audit)
     for r in _reqs(cfg, n, max_new):
         eng.submit(r)
-    done = eng.run()
-    return eng, done
+    if not profile:
+        return eng, eng.run()
+    logdir = tempfile.mkdtemp(prefix="obs-profile-")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    start, stop = PROFILED_STEPS
+    busy, i, bounds = True, 0, []
+    while busy:
+        if i == start:
+            jax.profiler.start_trace(logdir, profiler_options=opts)
+            bounds.append(now_ns())
+        busy = eng.step()
+        i += 1
+        if i == stop or (not busy and len(bounds) == 1):
+            bounds.append(now_ns())
+            jax.profiler.stop_trace()
+    assert not (eng.running or eng.queue or eng.preempted)
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    eng.profiled_ns = tuple(bounds)
+    eng.profiled_events = _host_events(path)
+    shutil.rmtree(logdir, ignore_errors=True)
+    return eng, list(eng.finished)
+
+
+def _host_events(path):
+    """``(name, start_ns, end_ns)`` of every ``engine.*`` host event of an
+    ``.xplane.pb``, on the obs clock: the file stores host events relative
+    to the session's ``profile_start_time``."""
+    from jax.profiler import ProfileData
+
+    planes = list(ProfileData.from_file(path).planes)
+    (t0,) = [dict(p.stats)["profile_start_time"] for p in planes
+             if p.name == "Task Environment"]
+    return [
+        (e.name, int(t0 + e.start_ns), int(t0 + e.start_ns + e.duration_ns))
+        for p in planes if p.name.startswith("/host:")
+        for line in p.lines for e in line.events
+        if e.name.startswith("engine.")
+    ]
 
 
 #: run cache — hypothesis revisits configurations, engine runs are the
@@ -90,10 +143,12 @@ _RUNS = {}
 
 
 def _cached_run(cfg, params, scheduler, spec_depth, obs_on):
+    """``obs_on``: tracer, audit and a few profiled steps all on."""
     key = (scheduler, spec_depth, obs_on)
     if key not in _RUNS:
         _RUNS[key] = _run(cfg, params, scheduler=scheduler,
-                          spec_depth=spec_depth, trace=obs_on, audit=obs_on)
+                          spec_depth=spec_depth, trace=obs_on, audit=obs_on,
+                          profile=obs_on)
     return _RUNS[key]
 
 
@@ -207,31 +262,72 @@ def test_validator_accepts_nested_and_adjacent():
     ]}) == []
 
 
+def test_span_nesting_self_time_and_counters():
+    obs = Observability()
+    with obs.span("outer") as outer:
+        with obs.span("inner") as a:
+            pass
+        with obs.span("inner") as b:
+            pass
+    assert outer.start_ns <= a.start_ns <= a.end_ns <= b.start_ns
+    assert b.end_ns <= outer.end_ns
+    assert outer.child_ns == (a.end_ns - a.start_ns) + (b.end_ns - b.start_ns)
+    assert outer.self_s == pytest.approx(
+        outer.seconds - a.seconds - b.seconds)
+    assert a.self_s == a.seconds  # no children
+    snap = obs.metrics.snapshot()
+    assert snap["span.outer.n"] == 1 and snap["span.inner.n"] == 2
+    assert snap["span.inner.s"] == pytest.approx(a.seconds + b.seconds)
+    assert snap["span.outer.s"] == pytest.approx(outer.seconds)
+    assert not obs._open  # every span left
+    # the tracer is off: nothing recorded beyond the counters
+    assert obs.tracer.to_chrome_trace()["traceEvents"] == []
+
+
 def test_tracer_logical_layout_and_groups():
-    tr = Tracer()
-    tr.begin_iteration(0, 0.0)
-    tr.request_begin(7, 0.0)
-    tr.begin_group("fused_step", subs=2)
-    tr.pass_span("main", "decode", None)
-    tr.pass_span("main", "verify", None)
-    tr.end_group()
-    tr.instant("commit", 0.5, rid=7)
-    tr.end_iteration(1.0)
-    tr.request_end(7, 1.0)
+    """Spans become nested slices of the host row, on the obs clock;
+    instants and request tracks carry obs-clock stamps too."""
+    obs = Observability(trace=True)
+    tr = obs.tracer
+    t0 = now_ns()
+    tr.request_begin(7, t0)
+    with obs.span("engine.step"):
+        with obs.span("engine.plan"):
+            pass
+        with obs.span("engine.launch"):
+            tr.instant("commit", rid=7, ready_at=2.5)
+    tr.request_end(7, now_ns())
     trace = tr.to_chrome_trace()
     assert validate_chrome_trace(trace) == []
-    names = [e["name"] for e in trace["traceEvents"] if e["ph"] == "X"]
-    assert "fused_step" in names and "decode" in names
-    # the fused parent covers its children
-    xs = {e["name"]: e for e in trace["traceEvents"] if e["ph"] == "X"}
-    par, d, v = xs["fused_step"], xs["decode"], xs["verify"]
-    assert par["ts"] <= d["ts"]
-    assert par["ts"] + par["dur"] >= v["ts"] + v["dur"]
+    evs = trace["traceEvents"]
+    xs = {e["name"]: e for e in evs if e["ph"] == "X"}
+    assert set(xs) == {"engine.step", "engine.plan", "engine.launch"}
+    assert all(e["tid"] == TID_HOST and e["cat"] == "host"
+               for e in xs.values())
+    step, plan, launch = xs["engine.step"], xs["engine.plan"], \
+        xs["engine.launch"]
+    assert step["ts"] <= plan["ts"] <= plan["ts"] + plan["dur"] <= launch["ts"]
+    assert launch["ts"] + launch["dur"] <= step["ts"] + step["dur"]
+    assert step["args"]["self_us"] <= step["dur"]
+    (commit,) = [e for e in evs if e["ph"] == "i"]
+    assert commit["tid"] == TID_PROTOCOL and commit["args"]["ready_at"] == 2.5
+    assert launch["ts"] <= commit["ts"] <= launch["ts"] + launch["dur"]
+    begin, end = [e for e in evs if e["ph"] in "be"]
+    assert begin["ts"] == t0 // 1000 <= step["ts"]
+    assert end["ts"] >= step["ts"] + step["dur"]
 
 
 # ----------------------------------------------------------------------
 # engine-exported traces (golden schema)
 # ----------------------------------------------------------------------
+
+#: host spans every traced LLM42 overlap run passes through
+ENGINE_SPANS = {
+    "engine.step", "engine.verdicts", "engine.retire", "engine.admit",
+    "engine.prefill", "engine.memory", "engine.plan", "engine.prep",
+    "engine.verify_prep", "engine.launch", "engine.wait", "engine.post",
+    "engine.verify_post",
+}
 
 
 def test_engine_trace_schema_and_attribution(model):
@@ -241,18 +337,20 @@ def test_engine_trace_schema_and_attribution(model):
     assert validate_chrome_trace(trace) == []
     evs = trace["traceEvents"]
 
-    # stream rows are named via metadata
+    # rows are named via metadata: host spans and protocol instants
     names = {e["args"]["name"] for e in evs if e["ph"] == "M"
              and e["name"] == "thread_name"}
-    assert {"main stream", "verify stream", "protocol"} <= names
+    assert names == {"host", "protocol"}
 
-    # pass slices land on their stream's row
-    rows = {e["tid"] for e in evs if e["ph"] == "X"}
-    assert TID_MAIN in rows
-    verify_slices = [e for e in evs if e["ph"] == "X"
-                     and e["name"] == "verify"]
-    assert verify_slices, "no verify passes traced"
-    assert all(e["tid"] in (TID_MAIN, TID_VERIFY) for e in verify_slices)
+    # every host span is a slice of the host row; the step encloses the rest
+    xs = [e for e in evs if e["ph"] == "X"]
+    assert all(e["tid"] == TID_HOST for e in xs)
+    assert ENGINE_SPANS <= {e["name"] for e in xs}
+    steps = [e for e in xs if e["name"] == "engine.step"]
+    assert len(steps) == eng.obs.metrics.snapshot()["span.engine.step.n"]
+    for e in xs:
+        assert any(s["ts"] <= e["ts"] and
+                   e["ts"] + e["dur"] <= s["ts"] + s["dur"] for s in steps)
 
     # per-request lifecycle: one async begin + one end per request
     begins = [e for e in evs if e["ph"] == "b"]
@@ -268,9 +366,13 @@ def test_engine_trace_schema_and_attribution(model):
 
 
 def test_engine_trace_costed_clock(model):
+    """Under the costed stream clock the trace stays on the obs clock: the
+    stream clock reaches it only as the verdict deadline a
+    ``verify_submit`` instant carries."""
     cfg, params = model
     from repro.configs import get_config
 
+    t0 = now_ns()
     eng = Engine(cfg, params, mode=Mode.LLM42, policy=DRIFTY, window=5,
                  group=2, max_batch=8, capacity=256,
                  scheduler=OverlapPolicy(), trace=True,
@@ -278,12 +380,41 @@ def test_engine_trace_costed_clock(model):
     for r in _reqs(cfg):
         eng.submit(r)
     eng.run()
+    t1 = now_ns()
     trace = eng.obs.tracer.to_chrome_trace()
     assert validate_chrome_trace(trace) == []
-    # costed spans carry real durations on the verify row
-    vs = [e for e in trace["traceEvents"]
-          if e["ph"] == "X" and e["tid"] == TID_VERIFY]
-    assert vs and all(e["dur"] > 0 for e in vs)
+    stamped = [e for e in trace["traceEvents"] if e["ph"] != "M"]
+    assert all(t0 // 1000 <= e["ts"] <= t1 // 1000 for e in stamped)
+    subs = [e for e in stamped if e["name"] == "verify_submit"]
+    assert subs and all(
+        0 < e["args"]["ready_at"] <= eng.runtime.makespan + 5e-3
+        for e in subs)
+
+
+def test_engine_spans_on_the_profilers_clock(model):
+    """Every ``engine.*`` span of the profiled steps is a host event of the
+    profiler's trace, nested as called, and the tracer's slice of it
+    starts and ends within 1 ms of the event: one clock."""
+    cfg, params = model
+    eng, _ = _cached_run(cfg, params, "overlap", 1, True)
+    lo, hi = eng.profiled_ns
+    spans = [(n, s, e) for n, s, e, _ in eng.obs.tracer.slices
+             if n.startswith("engine.") and lo <= s and e <= hi]
+    events = eng.profiled_events
+    assert {"engine.step", "engine.launch", "engine.wait"} <= {
+        n for n, _, _ in spans}
+    assert len(events) == len(spans)
+
+    def parent(item, items):
+        outer = [o for o in items if o is not item and o[1] <= item[1]
+                 and item[2] <= o[2]]
+        return min(outer, key=lambda o: o[2] - o[1])[0] if outer else None
+
+    for span in spans:
+        ev = min((e for e in events if e[0] == span[0]),
+                 key=lambda e: abs(e[1] - span[1]))
+        assert abs(ev[1] - span[1]) < 1e6 and abs(ev[2] - span[2]) < 1e6
+        assert parent(ev, events) == parent(span, spans), span[0]
 
 
 # ----------------------------------------------------------------------
@@ -297,9 +428,10 @@ def test_engine_trace_costed_clock(model):
     spec_depth=st.sampled_from([1, 4]),
 )
 def test_observability_is_observer_effect_free(model, scheduler, spec_depth):
-    """Tracing + auditing on vs off: committed streams bitwise identical
-    for EVERY request (deterministic and fast-path alike — the engine
-    launches identical device programs either way)."""
+    """Tracer, audit and profiler on vs off (spans are always on):
+    committed streams bitwise identical for EVERY request (deterministic
+    and fast-path alike — the engine launches identical device programs
+    either way)."""
     cfg, params = model
     _, done_on = _cached_run(cfg, params, scheduler, spec_depth, True)
     _, done_off = _cached_run(cfg, params, scheduler, spec_depth, False)
@@ -467,6 +599,12 @@ def test_engine_metrics_snapshot(model):
     assert snap["verify.rollback_depth.count"] == snap["verify.rollbacks"]
     assert snap["latency.ttft.count"] == len(done)
     assert snap["latency.e2e.count"] == len(done)
+    assert snap["latency.queue_wait.count"] == len(done)
+    assert 0 <= snap["latency.queue_wait.sum"] <= snap["latency.e2e.sum"]
+    # host spans: one engine.step per iteration, the rest inside it
+    assert snap["span.engine.step.n"] == snap["engine.iterations"]
+    for name in ENGINE_SPANS - {"engine.step"}:
+        assert 0 < snap[f"span.{name}.s"] <= snap["span.engine.step.s"]
     assert snap["engine.running"] == 0  # drained
     assert snap["engine.peak_running"] >= 1
     assert snap["blockpool.peak_blocks_in_use"] >= 1

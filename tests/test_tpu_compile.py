@@ -85,3 +85,60 @@ def test_fastpath_kernel_compiles_for_v5e(one_chip, B, bs):
         null_bid=NUM_BLOCKS, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_step_programs_carry_pass_scopes_and_kernel_names(one_chip,
+                                                          monkeypatch):
+    """The engine's fused step and decode step, lowered for a described
+    v5e, name their programs (``fused_step``, ``decode_step``), carry each
+    sub-pass's scope (``prefill_chunk``, ``decode``, ``verify``) and name
+    both paged kernels, so a device trace can split time by pass and find
+    each kernel after a refactor.  Smoke widths: nothing is compiled."""
+    from repro.configs import get_smoke_config
+    from repro.core.determinism import INVARIANT_SCHEDULE, Mode
+    from repro.kernels import ops
+    from repro.models import init_params
+    from repro.serving.engine import Engine
+
+    cfg = get_smoke_config("llama3-8b")
+    params = init_params(cfg, jax.random.key(0))
+    eng = Engine(cfg, params, mode=Mode.LLM42, window=5, group=2,
+                 max_batch=8, capacity=128, prefill_chunk=8)
+    # the table-walking kernels are the TPU branch of the paged forward
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def like(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    i32, f32 = jnp.int32, jnp.float32
+    nblk = eng.pool.layout.blocks_per_table
+    B, G, W, C = 2, eng.group, eng.window, eng._chunk_size()
+    pargs = (sds((), i32), sds((nblk,), i32),
+             sds((1, C, cfg.d_model), jnp.dtype(cfg.dtype)), sds((), i32),
+             sds((), i32))
+    dargs = (sds((B,), i32), sds((B, nblk), i32), sds((B,), i32),
+             sds((B,), i32), sds((B,), i32), sds((B,), f32),
+             sds((B,), i32), sds((B,), i32))
+    vargs = (sds((G,), i32), sds((G, nblk), i32), sds((G,), i32),
+             sds((G, W), i32), sds((G, W - 1), i32), sds((G,), i32),
+             sds((G,), i32), sds((G,), f32), sds((G,), i32), sds((G,), i32))
+    fast = eng._decode_schedule(B)
+    assert fast.kv_splits > 1  # the fast path's split kernel
+    fused = eng._fused_fn(C, B, fast, 1).__wrapped__
+    text = jax.jit(fused).lower(
+        like(params), like(eng.pool.data), None, pargs, dargs, (vargs,)
+    ).as_text(debug_info=True)
+    assert "@jit_fused_step" in text
+    for scope in ("prefill_chunk", "decode", "verify"):
+        assert f"jit(fused_step)/{scope}/" in text, scope
+    assert '"paged_attention_fast/pallas_call"' in text
+
+    decode = eng._decode_body(B, INVARIANT_SCHEDULE)  # one split: commit kernel
+    text = jax.jit(decode).lower(
+        like(params), like(eng.pool.data), *dargs
+    ).as_text(debug_info=True)
+    assert "@jit_decode_step" in text and "jit(decode_step)/decode/" in text
+    assert '"paged_attention/pallas_call"' in text
