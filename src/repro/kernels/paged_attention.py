@@ -24,10 +24,15 @@ Two variants, per the determinism contract:
   ``repro.analysis.kernel_lint``.
 * ``paged_attention_fast`` — the licensed fast path: kv-split flash-decode
   over the table (grid ``(B, KV, kv_splits)``), merging per-split partials
-  through f32 VMEM scratch.  Split count follows the workload, so its
-  schedule is nondeterministic by design and the function is exempted with
-  ``# det: fastpath`` (the taint pass proves it unreachable from the commit
-  side).
+  through f32 VMEM scratch.  It walks only each row's live blocks, those up
+  to ``q_pos // block_size`` (a row's table holds position ``p`` in block
+  ``p // block_size``, so later blocks hold nothing it may see): a split
+  past them issues no DMA and no compute, and a live split DMAs only its
+  live blocks and masks the rest of its view.  Skipping is exact, since a
+  fully masked split merges as a no-op.  Split count and walk follow the
+  workload, so its schedule is nondeterministic by design and the function
+  is exempted with ``# det: fastpath`` (the taint pass proves it
+  unreachable from the commit side).
 """
 
 from __future__ import annotations
@@ -170,45 +175,55 @@ def _paged_fast_kernel(
     m_ref, d_ref, acc_ref, *, kv_splits, blocks_per_split, blocks_per_row,
     block_size, scale, combine_dtype
 ):
-    b = pl.program_id(0)
-    s_idx = pl.program_id(2)
-    _fetch_view(
-        tab_ref, kp_hbm, vp_hbm, kbuf, vbuf, sem, row=b, head=pl.program_id(1),
-        lo=s_idx * blocks_per_split, n_blocks=blocks_per_split,
-        blocks_per_row=blocks_per_row, block_size=block_size,
-    )
-    q = q_ref[...].astype(F32) * scale  # (G, D)
-    d = q.shape[-1]
-    kv = kbuf[:, :d].astype(F32)
-    vv = vbuf[:, :d].astype(F32)
-    s = jax.lax.dot_general(
-        q, kv, (((1,), (1,)), ((), ())), preferred_element_type=F32,
-        precision=HIGHEST,
-    )
-    pv = pv_ref[...]  # (1, S / kv_splits): this split's positions
-    valid = (pv >= 0) & (pv <= qpos_ref[b])
-    s = jnp.where(valid, s, -jnp.inf)
-    m_c = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), -1e30)  # (G, 1)
-    e = jnp.exp(s - m_c).astype(combine_dtype)
-    d_c = jnp.sum(e, axis=-1, keepdims=True)  # (G, 1)
-    o_c = jnp.dot(e.astype(F32), vv, preferred_element_type=F32,
-                  precision=HIGHEST)  # (G, D)
+    b, h, s_idx = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    lo = s_idx * blocks_per_split
+    # live blocks of this split: the table holds position p in block
+    # p // block_size, so nothing past block q_pos // block_size is visible
+    n_fetch = jnp.clip(qpos_ref[b] // block_size + 1 - lo, 0, blocks_per_split)
 
-    @pl.when(s_idx == 0)
-    def _init():
-        m_ref[...] = m_c
-        d_ref[...] = d_c.astype(F32)
-        acc_ref[...] = o_c
+    # a dead split would merge m = -1e30, d = o = 0: a no-op on the scratch
+    @pl.when((s_idx == 0) | (n_fetch > 0))
+    def _split():
+        _fetch_view(
+            tab_ref, kp_hbm, vp_hbm, kbuf, vbuf, sem, row=b, head=h, lo=lo,
+            n_blocks=n_fetch, blocks_per_row=blocks_per_row,
+            block_size=block_size,
+        )
+        q = q_ref[...].astype(F32) * scale  # (G, D)
+        d = q.shape[-1]
+        kv = kbuf[:, :d].astype(F32)
+        vv = vbuf[:, :d].astype(F32)
+        fetched = n_fetch * block_size  # view rows past it hold stale data
+        rows = jax.lax.broadcasted_iota(jnp.int32, (vv.shape[0], 1), 0)
+        vv = jnp.where(rows < fetched, vv, 0.0)  # 0 * NaN would reach o_c
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())), preferred_element_type=F32,
+            precision=HIGHEST,
+        )
+        pv = pv_ref[...]  # (1, S / kv_splits): this split's positions
+        valid = (pv >= 0) & (pv <= qpos_ref[b])  # false on rows not fetched
+        s = jnp.where(valid, s, -jnp.inf)
+        m_c = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), -1e30)  # (G, 1)
+        e = jnp.exp(s - m_c).astype(combine_dtype)
+        d_c = jnp.sum(e, axis=-1, keepdims=True)  # (G, 1)
+        o_c = jnp.dot(e.astype(F32), vv, preferred_element_type=F32,
+                      precision=HIGHEST)  # (G, D)
 
-    @pl.when(s_idx > 0)
-    def _merge():
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, m_c)
-        a_prev = jnp.exp(m_prev - m_new)
-        a_c = jnp.exp(m_c - m_new)
-        m_ref[...] = m_new
-        d_ref[...] = d_ref[...] * a_prev + d_c.astype(F32) * a_c
-        acc_ref[...] = acc_ref[...] * a_prev + o_c * a_c
+        @pl.when(s_idx == 0)
+        def _init():
+            m_ref[...] = m_c
+            d_ref[...] = d_c.astype(F32)
+            acc_ref[...] = o_c
+
+        @pl.when(s_idx > 0)
+        def _merge():
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, m_c)
+            a_prev = jnp.exp(m_prev - m_new)
+            a_c = jnp.exp(m_c - m_new)
+            m_ref[...] = m_new
+            d_ref[...] = d_ref[...] * a_prev + d_c.astype(F32) * a_c
+            acc_ref[...] = acc_ref[...] * a_prev + o_c * a_c
 
     @pl.when(s_idx == kv_splits - 1)
     def _emit():
@@ -233,7 +248,8 @@ def paged_attention_fast(
     null_bid: int | None = None,
     interpret: bool = True,
 ) -> jax.Array:
-    """Fast-path paged attention: kv-split flash-decode over the table."""
+    """Fast-path paged attention: kv-split flash-decode over each row's
+    live blocks ``[0, q_pos // block_size]``."""
     nblk = tables.shape[1]
     if nblk % kv_splits != 0:
         raise ValueError(f"kv_splits={kv_splits} must divide table reach {nblk}")
@@ -242,6 +258,13 @@ def paged_attention_fast(
     _, _, bs, Dp = k_pool.shape
     per = nblk // kv_splits
     pv = pv.reshape(B * kv_splits, 1, per * bs)  # one row per (row, split)
+
+    def pos_block(b, h, s, t, p):
+        # a dead split names the last live split's block again, so the
+        # pipeline copies no positions for it
+        last = jnp.maximum(p[b], 0) // (per * bs)
+        return (b * kv_splits + jnp.minimum(s, last), 0, 0)
+
     out = pl.pallas_call(
         functools.partial(
             _paged_fast_kernel,
@@ -261,10 +284,7 @@ def paged_attention_fast(
                 ),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(
-                    (None, 1, per * bs),
-                    lambda b, h, s, t, p: (b * kv_splits + s, 0, 0),
-                ),
+                pl.BlockSpec((None, 1, per * bs), pos_block),
             ],
             out_specs=pl.BlockSpec(
                 (None, None, G, D), lambda b, h, s, t, p: (b, h, 0, 0)

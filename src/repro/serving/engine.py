@@ -371,6 +371,14 @@ class Engine:
                    unit="blocks", help="blocks returned to the free list")
         m.gauge_fn("blockpool.paged", lambda: int(self.pool.paged),
                    help="1 when full-attention KV is paged")
+        self._c_walk = m.counter(
+            "paged.walk_blocks", unit="blocks",
+            help="live table blocks (pos // block_size + 1) of the rows of "
+                 "paged single-token decode passes: what the fast-path "
+                 "kernel walks")
+        self._c_reach = m.counter(
+            "paged.reach_blocks", unit="blocks",
+            help="table reach (rows x blocks per table) of the same passes")
         if self.prefix_cache is not None:
             def _pc(key: str):
                 return lambda: self.prefix_cache.stats()[key]
@@ -1429,6 +1437,10 @@ class Engine:
             temps.append(r.sampling.temperature)
             top_ks.append(r.sampling.top_k)
             r.last_sched = self._now
+        if self._paged_fwd:
+            bs, nblk = self.pool.block_size, tables.shape[1]
+            self._c_walk.inc(sum(min(p // bs + 1, nblk) for p in pos))
+            self._c_reach.inc(len(batch) * nblk)
         args = (
             slots, tables,
             jnp.array(last_tok, jnp.int32), jnp.array(pos, jnp.int32),

@@ -85,6 +85,34 @@ def _rand_problem(seed, *, B=3, H=4, KV=2, D=8, NB=20, bs=4, nblk=5,
             jnp.asarray(q_pos), null_bid)
 
 
+def _live_problem(q_pos, *, tail, H=4, KV=2, D=8, bs=4, nblk=8):
+    """Rows at query positions ``q_pos``, each table holding its live
+    blocks ``[0, q_pos // bs]`` filled whole (positions past ``q_pos`` in
+    the last one, as a rolled-back speculation leaves them) and past them
+    ``tail``: ``"null"`` entries (-1), or ``"nan"``, real blocks of NaN
+    K/V whose positions lie above ``q_pos``."""
+    rng = np.random.default_rng(sum(q_pos))
+    B = len(q_pos)
+    NB = B * nblk + 2
+    null_bid = NB - 2
+    q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
+    k = rng.standard_normal((NB, KV, bs, D)).astype(np.float32)
+    v = rng.standard_normal((NB, KV, bs, D)).astype(np.float32)
+    k[null_bid:] = v[null_bid:] = 0.0
+    pos = np.full((NB, bs), -1, np.int32)
+    tables = np.full((B, nblk), -1, np.int32)
+    for b, p in enumerate(q_pos):
+        n_live = p // bs + 1
+        for j in range(nblk if tail == "nan" else n_live):
+            bid = b * nblk + j
+            tables[b, j] = bid
+            pos[bid] = np.arange(j * bs, (j + 1) * bs)
+            if j >= n_live:
+                k[bid] = v[bid] = np.nan
+    return (q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(pos),
+            jnp.asarray(tables), jnp.asarray(q_pos, jnp.int32), null_bid)
+
+
 class TestPagedKernel:
     @pytest.mark.parametrize("seed", [0, 1, 2])
     def test_commit_kernel_matches_oracle_bitwise(self, seed):
@@ -116,6 +144,7 @@ class TestPagedKernel:
         # combine rounds at different points (scratch stays f32 on-chip),
         # so agreement is only to bf16 precision
         (2, "float32", 1e-5),
+        (4, "float32", 1e-5),
         (4, "bfloat16", 2e-2),
     ])
     def test_fastpath_matches_split_oracle(self, splits, cd, tol):
@@ -129,6 +158,27 @@ class TestPagedKernel:
             null_bid=null_bid,
         )
         assert jnp.allclose(got, want, atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("splits", [2, 4])
+    @pytest.mark.parametrize("q_pos", [
+        (0, 3, 7, 5),  # every live block in split 0 of a 4-split table
+        (2, 17, 31),  # one live block; live blocks across every split
+        (9, 30, 4, 12),
+    ], ids=["split0-only", "single-block", "mixed"])
+    def test_fastpath_walks_only_live_blocks(self, q_pos, splits):
+        """The fast path reads no block past ``q_pos // bs``: table entries
+        there holding real blocks of NaN K/V (positions above ``q_pos``)
+        give the output of ``-1`` entries, bit for bit, and that output
+        agrees with the oracle."""
+        null = _live_problem(q_pos, tail="null")
+        nan = _live_problem(q_pos, tail="nan")
+        kw = dict(kv_splits=splits, combine_dtype="float32")
+        got = pk.paged_attention_fast(*null[:-1], null_bid=null[-1], **kw)
+        got_nan = pk.paged_attention_fast(*nan[:-1], null_bid=nan[-1], **kw)
+        assert bool(jnp.all(jnp.isfinite(got_nan)))
+        assert jnp.array_equal(got_nan, got)
+        want = ref.paged_attention(*null[:-1], null_bid=null[-1], **kw)
+        assert jnp.allclose(got, want, atol=1e-5, rtol=1e-5)
 
     def test_fastpath_split_count_changes_result(self):
         """Sanity that the fast path really is schedule-dependent — the
@@ -252,6 +302,22 @@ class TestFusedStepStructure:
             assert len(leads) == 1, e
             saw_fused |= len(subs) > 1
         assert saw_fused
+
+    def test_decode_passes_count_their_live_walk(self):
+        """Each paged decode pass adds its rows' live blocks to
+        ``paged.walk_blocks`` and their table reach to
+        ``paged.reach_blocks``: the walk is positive and within the reach,
+        and the reach is the table width times every decoded row."""
+        cfg, params = _model()
+        _, eng = _run(cfg, params, paged=True, scheduler="overlap")
+        snap = eng.obs.metrics.snapshot()
+        walk, reach = snap["paged.walk_blocks"], snap["paged.reach_blocks"]
+        from repro.serving.costmodel import flatten_events
+        rows = sum(e["batch"] for e in flatten_events(eng.events)
+                   if e.get("kind") == "decode")
+        assert rows > 0
+        assert reach == rows * eng.pool.layout.blocks_per_table
+        assert 0 < walk <= reach
 
     def test_legacy_engine_never_marks_fused(self):
         cfg, params = _model()
